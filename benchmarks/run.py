@@ -17,7 +17,9 @@ def emit(name: str, us_per_call: float, derived: str = "") -> None:
     print(f"{name},{us_per_call:.2f},{derived}")
 
 
-def main() -> None:
+def main() -> int:
+    """Run every module (or the one named); exit status 1 when any
+    module failed — a ``__FAILED`` row alone would pass in CI."""
     only = sys.argv[1] if len(sys.argv) > 1 else ""
     from benchmarks import (bench_pruning, bench_quant, bench_roofline,
                             bench_serving, bench_skipclip, bench_throughput)
@@ -26,6 +28,7 @@ def main() -> None:
         "skipclip": bench_skipclip, "throughput": bench_throughput,
         "roofline": bench_roofline, "serving": bench_serving,
     }
+    failed = []
     for name, mod in mods.items():
         if only and only != name:
             continue
@@ -34,7 +37,9 @@ def main() -> None:
         except Exception as e:
             emit(f"{name}__FAILED", 0.0, f"{type(e).__name__}:{e}")
             traceback.print_exc()
+            failed.append(name)
+    return 1 if failed else 0
 
 
 if __name__ == '__main__':
-    main()
+    sys.exit(main())

@@ -9,8 +9,8 @@ Two halves share this package:
   walker over the REAL traced serving programs (``jaxpr_walk``,
   ``targets``) and an AST linter over ``src/repro`` — plus a runtime
   retrace audit. ``python -m repro.analysis`` runs it; the CI fast
-  gate blocks on it. Rules: no-materialization, precision, compat,
-  host-sync, trace-stability (see ``repro/serving/__init__.py``,
+  gate blocks on it. Rules: no-materialization, precision, host-sync,
+  trace-stability (see ``repro/serving/__init__.py``,
   "Enforced invariants", for the contracts they pin).
 
 Only stdlib-light names are re-exported here so ``import

@@ -7,7 +7,7 @@ invariants") for what each rule guards.
 
 Usage:
     python -m repro.analysis                     # all rules
-    python -m repro.analysis --rules compat,host-sync
+    python -m repro.analysis --rules precision,host-sync
     python -m repro.analysis --list-rules
     python -m repro.analysis --json              # machine-readable
     python -m repro.analysis --allow 'precision:qmatmul*'

@@ -19,8 +19,7 @@ import dataclasses
 import importlib
 from typing import Callable, Dict, List, Optional, Sequence
 
-_BUILTIN = ("materialization", "precision", "compat_gate", "host_sync",
-            "trace_stability")
+_BUILTIN = ("materialization", "precision", "host_sync", "trace_stability")
 
 RULES: Dict[str, "Rule"] = {}
 

@@ -68,14 +68,17 @@ class TraceTarget:
     def view_floor(self, operand_shape: Sequence[int]) -> Optional[int]:
         """Size of the ``(B, T*block_len, ...)`` logical view a gather
         from an arena-shaped operand would materialize — None when the
-        operand is not arena-shaped for this target."""
+        operand is not arena-shaped for this target. Arenas are
+        ``(n_blocks, block_len, ...)`` (MLA latents) or heads-major
+        ``(n_blocks, Hkv, block_len, ...)`` (GQA K/V)."""
         if len(operand_shape) < 3:
             return None
-        T = self.arena_sigs.get((operand_shape[0], operand_shape[1]))
-        if T is None:
-            return None
-        feat = math.prod(operand_shape[2:])
-        return self.n_slots * T * self.block_len * feat
+        for ax in (1, 2):
+            T = self.arena_sigs.get((operand_shape[0], operand_shape[ax]))
+            if T is not None:
+                feat = math.prod(operand_shape[1:]) // operand_shape[ax]
+                return self.n_slots * T * self.block_len * feat
+        return None
 
 
 def _pool_sigs(pool) -> Dict[Tuple[int, int], int]:
@@ -207,8 +210,8 @@ def attention_op_targets(backends: Sequence[str] = BACKENDS
                     (jnp.float32, False, "fp32"),
                     (jnp.bfloat16, False, "bf16"),
                     (jnp.int8, True, "int8")):
-                k = jnp.zeros((Nb, bl, Hkv, hd), cdt)
-                sc = (jnp.zeros((Nb, bl, Hkv), jnp.float32) if scales
+                k = jnp.zeros((Nb, Hkv, bl, hd), cdt)
+                sc = (jnp.zeros((Nb, Hkv, bl), jnp.float32) if scales
                       else None)
                 jx = jax.make_jaxpr(
                     lambda q, k, v, pos, t, table, ks, vs:

@@ -35,10 +35,19 @@ def interpret_default() -> bool:
     old per-module ``INTERPRET = jax.default_backend() == "cpu"``
     constants froze the answer at import time, so flipping the backend
     afterwards ran compiled kernels on CPU or interpret on TPU).
-    ``REPRO_PALLAS_INTERPRET=1|0`` force-overrides (tests)."""
+    ``REPRO_PALLAS_INTERPRET=1|0`` force-overrides off the TPU (tests);
+    on a TPU the kernels always compile, and asking for the interpreter
+    there is an error rather than a silent slow path."""
     env = os.environ.get("REPRO_PALLAS_INTERPRET", "")
+    wants = env not in ("", "0", "false", "no")
+    if jax.default_backend() == "tpu":
+        if wants:
+            raise RuntimeError(
+                f"REPRO_PALLAS_INTERPRET={env!r} on a TPU backend: the "
+                f"Pallas interpreter is a CPU test tool; unset it")
+        return False
     if env:
-        return env not in ("0", "false", "no")
+        return wants
     return jax.default_backend() == "cpu"
 
 
@@ -82,9 +91,9 @@ def decode_gqa(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
 
     q: (B, C, H, hd); pos: (B, L); t: (B, C) (< 0 = pad row).
     ``table`` None: k/v are contiguous per-slot rows (B, L, Hkv, hd).
-    ``table`` (B, T): k/v are shared arenas (n_blocks, block_len, Hkv,
-    hd) and the table maps logical to arena blocks (-1 = unassigned).
-    Returns (B, C, H*hd).
+    ``table`` (B, T): k/v are shared heads-major arenas (n_blocks, Hkv,
+    block_len, hd) and the table maps logical to arena blocks (-1 =
+    unassigned). Returns (B, C, H*hd).
 
     ``backend`` ``xla``/None: the gather reference — materialises the
     (B, T*block_len) logical view per call. ``pallas``: the fused
@@ -92,13 +101,13 @@ def decode_gqa(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
     ``gqa_paged_p``, multi-token chunk steps (C > 1) run
     ``gqa_paged_chunk_p`` with a per-query causal mask; both apply the
     identical masking contract, so emitted tokens do not depend on the
-    backend. The contiguous layout runs fused too, viewed as a B-block
-    arena with an identity table. ``shard_kv`` optionally constrains
-    the gathered reads (flash-decoding sharding annotation; reference
-    path only).
+    backend. The contiguous layout runs fused too, transposed into a
+    B-block arena with an identity table. ``shard_kv`` optionally
+    constrains the gathered reads (flash-decoding sharding annotation;
+    reference path only).
 
-    ``k_scale``/``v_scale``: int8-arena dequant scales (n_blocks,
-    block_len, Hkv) fp32 — paged layout only. The fused path DMAs them
+    ``k_scale``/``v_scale``: int8-arena dequant scales (n_blocks, Hkv,
+    block_len) fp32 — paged layout only. The fused path DMAs them
     alongside their value blocks and dequantizes in-register; the
     reference gathers them with the SAME clamped indices and
     dequantizes through the identical :func:`pa.dequantize_kv`
@@ -110,12 +119,12 @@ def decode_gqa(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
         raise ValueError("int8 KV scales require the paged layout "
                          "(contiguous caches store bf16/fp8 directly)")
     if backend == "pallas":
-        if table is None:
-            karena, varena = k, v          # (B, L, Hkv, hd) == B blocks of L
+        if table is None:                  # B blocks of L, heads-major
+            karena, varena = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             tbl = jnp.arange(B, dtype=jnp.int32)[:, None]
         else:
             karena, varena, tbl = k, v, table
-        Hkv = k.shape[2]
+        Hkv = karena.shape[1]
         if C == 1:
             group = H // Hkv
             qh = q.reshape(B, Hkv, group, hd)
@@ -127,17 +136,17 @@ def decode_gqa(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
                                     window=window, k_scale=k_scale,
                                     v_scale=v_scale, interpret=interpret)
     if table is not None:
-        Hkv = k.shape[2]
-        bl = k.shape[1]
+        Hkv, bl = k.shape[1], k.shape[2]
         gidx = jnp.maximum(table, 0)
         Leff = table.shape[1] * bl
-        k_read = k[gidx].reshape(B, Leff, Hkv, hd)
-        v_read = v[gidx].reshape(B, Leff, Hkv, hd)
+
+        def view(a):                       # (B, T, Hkv, bl, ...) -> logical
+            a = jnp.swapaxes(a[gidx], 2, 3)
+            return a.reshape((B, Leff) + a.shape[3:])
+        k_read, v_read = view(k), view(v)
         if quantized:
-            k_read = pa.dequantize_kv(
-                k_read, k_scale[gidx].reshape(B, Leff, Hkv))
-            v_read = pa.dequantize_kv(
-                v_read, v_scale[gidx].reshape(B, Leff, Hkv))
+            k_read = pa.dequantize_kv(k_read, view(k_scale))
+            v_read = pa.dequantize_kv(v_read, view(v_scale))
         if shard_kv is not None:
             k_read = shard_kv(k_read)
             v_read = shard_kv(v_read)

@@ -43,9 +43,19 @@ blocks; causal-within-chunk falls out of the position mask because the
 chunk's K/V is scattered into the arena before the kernel runs).
 
 Rows with no valid position (pad slots, ``t < 0``) produce garbage in
-both backends — the scheduler never reads them. On TPU, block_len and
-the head dims want the usual (8, 128) tiling multiples; interpret mode
-(CPU CI) runs any shape.
+both backends — the scheduler never reads them.
+
+Layouts follow the TPU rule that a block's last two dims be (8, 128)
+multiples or the array's full dims. GQA arenas are heads-major,
+``(n_blocks, Hkv, block_len, hd)``, so one head's block is a whole
+``(block_len, hd)`` tile; their int8 scales are ``(n_blocks, Hkv,
+block_len)`` and DMA whole per block — viewing them as one row per
+(block, head) would relayout the whole scale arena on every call when
+Hkv is not a multiple of 8 (20 for qwen1.5-4b). ``pos`` and the MLA
+latent scales ride as ``(rows, 1, block_len)`` rows and per-query
+positions as ``(B, rows, 1)`` columns. A scale row becomes the
+``(block_len, 1)`` column the dequant needs by an exact masked-sum
+transpose in-register (:func:`_col`, :func:`_head_col`).
 """
 from __future__ import annotations
 
@@ -137,8 +147,39 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype=jnp.bfloat16,
     """Inverse of :func:`quantize_kv` — fp32 multiply, then cast to the
     compute dtype (bf16, matching the 1-byte-cache convention). Both
     backends MUST dequantize through this exact expression."""
-    return (q.astype(jnp.float32)
-            * jnp.expand_dims(scale.astype(jnp.float32), axis)).astype(dtype)
+    return _dequant(q, jnp.expand_dims(scale, axis), dtype)
+
+
+def _dequant(q: jax.Array, scale: jax.Array, dtype=jnp.bfloat16):
+    """The one dequant expression, ``scale`` already broadcastable."""
+    return (q.astype(jnp.float32) * scale.astype(jnp.float32)).astype(dtype)
+
+
+def _head_col(sc: jax.Array, h) -> jax.Array:
+    """Row ``h`` of a ``(rows, n)`` scale tile as an ``(n, 1)`` column,
+    selected by an exact masked sum."""
+    row = jnp.sum(jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0) == h, sc, 0.0),
+        axis=0, keepdims=True)                              # (1, n)
+    return _col(row)
+
+
+def _col(row: jax.Array) -> jax.Array:
+    """A ``(1, n)`` scale row as an ``(n, 1)`` column.
+
+    Mosaic has no cheap lane-to-sublane reshape, so transpose by a
+    masked sum: every sum adds one value to zeros, which is exact."""
+    n = row.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _pos_rows(pos: jax.Array, T: int) -> jax.Array:
+    """(B, T*block_len) positions -> (B*T, 1, block_len): one row per
+    (slot, logical block), a block the TPU lowering accepts."""
+    B, L = pos.shape
+    return pos.reshape(B * T, 1, L // T)
 
 
 # ---------------------------------------------------------------------------
@@ -196,23 +237,59 @@ def mla_reference(q_abs: jax.Array, q_rope: jax.Array, c_read: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# Fused Pallas backend — online softmax shared by every kernel below
+
+
+def _online_softmax_step(s, v, m_ref, l_ref, acc_ref):
+    """Fold one block's masked scores ``s`` (rows, bl) and values ``v``
+    (bl, d) into the running max / denominator / accumulator."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _init_stats(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _stats_scratch(rows: int, d: int):
+    return [pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32)]
+
+
+# ---------------------------------------------------------------------------
 # Fused Pallas backend — GQA
 
 
-def _gqa_kernel(tbl_ref, t_ref, q_ref, k_ref, v_ref, *rest,
-                scale: float, window: int, nT: int, quantized: bool = False):
-    if quantized:      # int8 arena rides with per-token-per-head scales
-        ks_ref, vs_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
+def _gqa_kernel(tbl_ref, *refs, scale: float, window: int, nT: int,
+                quantized: bool, chunk: bool):
+    """One (row b, KV head h, logical block j) grid step. Decode ticks
+    (``chunk`` False) mask against the scalar-prefetched ``t[b]``; chunk
+    ticks carry a per-query position column ``tq`` (rows, 1)."""
+    if chunk:
+        q_ref, k_ref, v_ref, *rest = refs
     else:
-        pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
-    b, j = pl.program_id(0), pl.program_id(2)
+        t_ref, q_ref, k_ref, v_ref, *rest = refs
+    ks_ref = vs_ref = None
+    if quantized:      # int8 arena rides with per-token-per-head scales
+        ks_ref, vs_ref, *rest = rest
+    if chunk:
+        tq_ref, *rest = rest
+    pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_stats(m_ref, l_ref, acc_ref)
 
     # unassigned (-1) logical blocks contribute nothing: skip the whole
     # tile (their pos words are EMPTY_POS anyway — writes drop in
@@ -221,40 +298,78 @@ def _gqa_kernel(tbl_ref, t_ref, q_ref, k_ref, v_ref, *rest,
     def _body():
         # mirror the reference's compute dtypes (gqa_reference): QK/PV
         # inputs in the cache dtype (bf16 for 1-byte storage — int8
-        # dequantizes in-register through the same quantize_kv rule the
-        # reference uses), fp32 scores/stats/accumulation — keeps
-        # fused-vs-reference numerics matched at every cache dtype
+        # dequantizes in-register through the same rule the reference
+        # uses), fp32 scores/stats/accumulation
         cdt = jnp.bfloat16 if jnp.dtype(k_ref.dtype).itemsize == 1 \
             else k_ref.dtype
-        q = q_ref[0, 0].astype(cdt)                    # (group, hd)
+        q = q_ref[0, 0].astype(cdt)                    # (rows, hd)
         if quantized:
-            k = dequantize_kv(k_ref[0, :, 0], ks_ref[0, :, 0])  # (bl, hd)
-            v = dequantize_kv(v_ref[0, :, 0], vs_ref[0, :, 0])
+            k = _dequant(k_ref[0, 0], _head_col(ks_ref[0], h))  # (bl, hd)
+            v = _dequant(v_ref[0, 0], _head_col(vs_ref[0], h))
         else:
-            k = k_ref[0, :, 0].astype(cdt)             # (bl, hd)
-            v = v_ref[0, :, 0].astype(cdt)
+            k = k_ref[0, 0].astype(cdt)                # (bl, hd)
+            v = v_ref[0, 0].astype(cdt)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        pos = pos_ref[0]                               # (bl,) int32
-        tq = t_ref[b]
+        pos = pos_ref[0]                               # (1, bl) int32
+        tq = tq_ref[0] if chunk else t_ref[b]          # (rows, 1) | scalar
         valid = (pos >= 0) & (pos <= tq)
         if window > 0:
             valid &= pos > tq - window
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(cdt), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _online_softmax_step(jnp.where(valid, s, NEG_INF), v,
+                             m_ref, l_ref, acc_ref)
 
     @pl.when(j == nT - 1)
     def _done():
         o_ref[0, 0] = (acc_ref[...] /
                        jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _gqa_call(qh, k, v, pos, table, *, window, k_scale, v_scale,
+              interpret, t=None, tq=None):
+    """Shared pallas_call of the GQA kernels. qh: (B, Hkv, rows, hd);
+    k/v: (n_blocks, Hkv, block_len, hd); pos: (B, T*block_len); exactly
+    one of ``t`` (B,) — decode — or ``tq`` (B, rows) — chunk — given."""
+    B, Hkv, rows, hd = qh.shape
+    bl = k.shape[2]
+    T = table.shape[1]
+    quantized = k_scale is not None
+    chunk = tq is not None
+    kern = functools.partial(_gqa_kernel, scale=hd ** -0.5, window=window,
+                             nT=T, quantized=quantized, chunk=chunk)
+
+    def blk(b, h, j, tbl, *_):
+        return jnp.maximum(tbl[b, j], 0)
+
+    in_specs = [
+        pl.BlockSpec((1, 1, rows, hd), lambda b, h, j, *_: (b, h, 0, 0)),
+        *[pl.BlockSpec((1, 1, bl, hd),
+                       lambda b, h, j, *r: (blk(b, h, j, *r), h, 0, 0))] * 2,
+        *[pl.BlockSpec((1, Hkv, bl),
+                       lambda b, h, j, *r: (blk(b, h, j, *r), 0, 0))]
+        * (2 if quantized else 0),
+        *([pl.BlockSpec((1, rows, 1), lambda b, h, j, *_: (b, 0, 0))]
+          if chunk else []),
+        pl.BlockSpec((1, 1, bl), lambda b, h, j, *_: (b * T + j, 0, 0)),
+    ]
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1 if chunk else 2,      # table (, t)
+        grid=(B, Hkv, T),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, rows, hd),
+                               lambda b, h, j, *_: (b, h, 0, 0)),
+        scratch_shapes=_stats_scratch(rows, hd),
+    )
+    scalars = (table.astype(jnp.int32),) if chunk \
+        else (table.astype(jnp.int32), t.astype(jnp.int32))
+    args = (qh, k, v) + ((k_scale, v_scale) if quantized else ()) \
+        + ((tq.astype(jnp.int32)[..., None],) if chunk else ()) \
+        + (_pos_rows(pos, T),)
+    return pl.pallas_call(
+        kern, grid_spec=spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, hd), qh.dtype),
+        interpret=_interpret(interpret),
+    )(*scalars, *args)
 
 
 def gqa_paged_p(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
@@ -263,74 +378,43 @@ def gqa_paged_p(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
                 v_scale: jax.Array | None = None,
                 interpret: bool | None = None) -> jax.Array:
     """Fused paged GQA decode. q: (B, Hkv, group, hd); k/v: arenas
-    (n_blocks, block_len, Hkv, hd); pos: (B, T*block_len); t: (B,);
+    (n_blocks, Hkv, block_len, hd); pos: (B, T*block_len); t: (B,);
     table: (B, T). Returns (B, Hkv, group, hd) in q's dtype.
 
     Grid (B, Hkv, T), block axis innermost: the table is a scalar-
-    prefetch operand, so each step's index_map DMAs arena block
-    ``table[b, j]`` straight into VMEM — the logical (B, T*block_len)
-    view is never materialised. Rows with no valid position produce
-    garbage (the scheduler ignores them).
+    prefetch operand, so each step's index_map DMAs head h of arena
+    block ``table[b, j]`` straight into VMEM — the logical
+    (B, T*block_len) view is never materialised. Rows with no valid
+    position produce garbage (the scheduler ignores them).
 
     ``k_scale``/``v_scale`` (int8 arenas only): fp32 scale arenas
-    (n_blocks, block_len, Hkv), DMA'd per grid step alongside their
-    value block via the SAME index_map and dequantized in-register."""
-    B, Hkv, group, hd = q.shape
-    bl = k.shape[1]
-    T = table.shape[1]
-    quantized = k_scale is not None
-    kern = functools.partial(_gqa_kernel, scale=hd ** -0.5, window=window,
-                             nT=T, quantized=quantized)
-    kv_spec = pl.BlockSpec(
-        (1, bl, 1, hd),
-        lambda b, h, j, tbl, t: (jnp.maximum(tbl[b, j], 0), 0, h, 0))
-    sc_spec = pl.BlockSpec(
-        (1, bl, 1),
-        lambda b, h, j, tbl, t: (jnp.maximum(tbl[b, j], 0), 0, h))
-    in_specs = [
-        pl.BlockSpec((1, 1, group, hd), lambda b, h, j, tbl, t: (b, h, 0, 0)),
-        kv_spec, kv_spec,
-        *([sc_spec, sc_spec] if quantized else []),
-        pl.BlockSpec((1, bl), lambda b, h, j, tbl, t: (b, j)),
-    ]
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                      # table, t
-        grid=(B, Hkv, T),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, hd),
-                               lambda b, h, j, tbl, t: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, hd), jnp.float32),
-        ],
-    )
-    args = (q, k, v) + ((k_scale, v_scale) if quantized else ()) + (pos,)
-    return pl.pallas_call(
-        kern, grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd), q.dtype),
-        interpret=_interpret(interpret),
-    )(table.astype(jnp.int32), t.astype(jnp.int32), *args)
+    (n_blocks, Hkv, block_len), DMA'd per grid step alongside their
+    value block and dequantized in-register."""
+    return _gqa_call(q, k, v, pos, table, window=window, k_scale=k_scale,
+                     v_scale=v_scale, interpret=interpret, t=t)
 
 
 # ---------------------------------------------------------------------------
 # Fused Pallas backend — MLA (absorbed latent form)
 
 
-def _mla_kernel(tbl_ref, t_ref, qa_ref, qr_ref, c_ref, kr_ref, *rest,
-                scale: float, nT: int, quantized: bool = False):
-    if quantized:      # int8 latent arena: per-token fp32 scale rows
-        cs_ref, krs_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
+def _mla_kernel(tbl_ref, *refs, scale: float, nT: int, quantized: bool,
+                chunk: bool):
+    if chunk:
+        qa_ref, qr_ref, c_ref, kr_ref, *rest = refs
     else:
-        pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
-        cs_ref = krs_ref = None
+        t_ref, qa_ref, qr_ref, c_ref, kr_ref, *rest = refs
+    cs_ref = krs_ref = None
+    if quantized:      # int8 latent arena: per-token fp32 scale rows
+        cs_ref, krs_ref, *rest = rest
+    if chunk:
+        tq_ref, *rest = rest
+    pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_stats(m_ref, l_ref, acc_ref)
 
     @pl.when(tbl_ref[b, j] >= 0)
     def _body():
@@ -338,35 +422,76 @@ def _mla_kernel(tbl_ref, t_ref, qa_ref, qr_ref, c_ref, kr_ref, *rest,
         # cache dtype (bf16 once an int8 block is dequantized) with fp32
         # accumulation; softmax stats fp32
         if quantized:
-            c = dequantize_kv(c_ref[0], cs_ref[0])     # (bl, kvr) bf16
-            kr = dequantize_kv(kr_ref[0], krs_ref[0])  # (bl, rope_d)
+            c = _dequant(c_ref[0], _col(cs_ref[0]))      # (bl, kvr)
+            kr = _dequant(kr_ref[0], _col(krs_ref[0]))
         else:
             c = c_ref[0]                               # (bl, kvr)
             kr = kr_ref[0]                             # (bl, rope_d)
-        cdt = c.dtype
-        qa = qa_ref[0].astype(cdt)                     # (H, kvr)
-        qr = qr_ref[0].astype(kr.dtype)                # (H, rope_d)
+        qa = qa_ref[0].astype(c.dtype)                 # (rows, kvr)
+        qr = qr_ref[0].astype(kr.dtype)                # (rows, rope_d)
         s = jax.lax.dot_general(qa, c, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
         s = s * scale
-        pos = pos_ref[0]
-        valid = (pos >= 0) & (pos <= t_ref[b])
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(cdt), c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        pos = pos_ref[0]                               # (1, bl)
+        tq = tq_ref[0] if chunk else t_ref[b]
+        valid = (pos >= 0) & (pos <= tq)
+        _online_softmax_step(jnp.where(valid, s, NEG_INF), c,
+                             m_ref, l_ref, acc_ref)
 
     @pl.when(j == nT - 1)
     def _done():
         o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _mla_call(qa, qr, c, kr, pos, table, *, scale, c_scale, kr_scale,
+              interpret, t=None, tq=None):
+    """Shared pallas_call of the MLA kernels. qa: (B, rows, kvr); qr:
+    (B, rows, rope_d); c/kr: (n_blocks, block_len, kvr|rope_d)."""
+    B, rows, kvr = qa.shape
+    rope_d = qr.shape[-1]
+    n_blocks, bl = c.shape[:2]
+    T = table.shape[1]
+    quantized = c_scale is not None
+    chunk = tq is not None
+    kern = functools.partial(_mla_kernel, scale=scale, nT=T,
+                             quantized=quantized, chunk=chunk)
+
+    def blk(b, j, tbl, *_):
+        return jnp.maximum(tbl[b, j], 0)
+
+    in_specs = [
+        pl.BlockSpec((1, rows, kvr), lambda b, j, *_: (b, 0, 0)),
+        pl.BlockSpec((1, rows, rope_d), lambda b, j, *_: (b, 0, 0)),
+        pl.BlockSpec((1, bl, kvr), lambda b, j, *r: (blk(b, j, *r), 0, 0)),
+        pl.BlockSpec((1, bl, rope_d),
+                     lambda b, j, *r: (blk(b, j, *r), 0, 0)),
+        *[pl.BlockSpec((1, 1, bl), lambda b, j, *r: (blk(b, j, *r), 0, 0))]
+        * (2 if quantized else 0),
+        *([pl.BlockSpec((1, rows, 1), lambda b, j, *_: (b, 0, 0))]
+          if chunk else []),
+        pl.BlockSpec((1, 1, bl), lambda b, j, *_: (b * T + j, 0, 0)),
+    ]
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1 if chunk else 2,
+        grid=(B, T),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, rows, kvr), lambda b, j, *_: (b, 0, 0)),
+        scratch_shapes=_stats_scratch(rows, kvr),
+    )
+    scalars = (table.astype(jnp.int32),) if chunk \
+        else (table.astype(jnp.int32), t.astype(jnp.int32))
+    scales = ((c_scale.reshape(n_blocks, 1, bl),
+               kr_scale.reshape(n_blocks, 1, bl)) if quantized else ())
+    args = (qa, qr, c, kr) + scales \
+        + ((tq.astype(jnp.int32)[..., None],) if chunk else ()) \
+        + (_pos_rows(pos, T),)
+    return pl.pallas_call(
+        kern, grid_spec=spec,
+        out_shape=jax.ShapeDtypeStruct((B, rows, kvr), jnp.float32),
+        interpret=_interpret(interpret),
+    )(*scalars, *args)
 
 
 def mla_paged_p(q_abs: jax.Array, q_rope: jax.Array, c: jax.Array,
@@ -382,45 +507,9 @@ def mla_paged_p(q_abs: jax.Array, q_rope: jax.Array, c: jax.Array,
     caller applies the absorbed value projection. ``c_scale``/
     ``kr_scale`` (int8 arenas only): per-token fp32 scale arenas
     (n_blocks, block_len) riding the same index_map as their blocks."""
-    B, H, kvr = q_abs.shape
-    rope_d = q_rope.shape[-1]
-    bl = c.shape[1]
-    T = table.shape[1]
-    quantized = c_scale is not None
-    kern = functools.partial(_mla_kernel, scale=scale, nT=T,
-                             quantized=quantized)
-    sc_spec = pl.BlockSpec(
-        (1, bl), lambda b, j, tbl, t: (jnp.maximum(tbl[b, j], 0), 0))
-    in_specs = [
-        pl.BlockSpec((1, H, kvr), lambda b, j, tbl, t: (b, 0, 0)),
-        pl.BlockSpec((1, H, rope_d), lambda b, j, tbl, t: (b, 0, 0)),
-        pl.BlockSpec((1, bl, kvr),
-                     lambda b, j, tbl, t: (jnp.maximum(tbl[b, j], 0),
-                                           0, 0)),
-        pl.BlockSpec((1, bl, rope_d),
-                     lambda b, j, tbl, t: (jnp.maximum(tbl[b, j], 0),
-                                           0, 0)),
-        *([sc_spec, sc_spec] if quantized else []),
-        pl.BlockSpec((1, bl), lambda b, j, tbl, t: (b, j)),
-    ]
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, T),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, kvr), lambda b, j, tbl, t: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, kvr), jnp.float32),
-        ],
-    )
-    args = (q_abs, q_rope, c, kr) \
-        + ((c_scale, kr_scale) if quantized else ()) + (pos,)
-    return pl.pallas_call(
-        kern, grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, kvr), jnp.float32),
-        interpret=_interpret(interpret),
-    )(table.astype(jnp.int32), t.astype(jnp.int32), *args)
+    return _mla_call(q_abs, q_rope, c, kr, pos, table, scale=scale,
+                     c_scale=c_scale, kr_scale=kr_scale,
+                     interpret=interpret, t=t)
 
 
 # ---------------------------------------------------------------------------
@@ -430,63 +519,12 @@ def mla_paged_p(q_abs: jax.Array, q_rope: jax.Array, c: jax.Array,
 # kernels key their mask off a scalar per-row position ``t``; here every
 # query token has its OWN position, so the chunk folds into the query-
 # row axis (C*group rows for GQA, C*H for MLA) and a per-query position
-# vector ``tq`` rides in as a VMEM operand. The mask
-# ``(pos >= 0) & (pos <= tq[:, None])`` then gives each chunk token its
-# own causal frontier — causal-within-chunk for free, since the chunk's
-# K/V is already scattered into the arena when the kernel reads it.
-# Pad tokens (t < 0) mask every position and emit garbage rows the
+# column ``tq`` rides in as a VMEM operand. The mask
+# ``(pos >= 0) & (pos <= tq)`` then gives each chunk token its own
+# causal frontier — causal-within-chunk for free, since the chunk's K/V
+# is already scattered into the arena when the kernel reads it. Pad
+# tokens (t < 0) mask every position and emit garbage rows the
 # scheduler never reads (their l stays 0; the output is acc/max(l,eps)).
-
-
-def _gqa_chunk_kernel(tbl_ref, q_ref, k_ref, v_ref, *rest,
-                      scale: float, window: int, nT: int,
-                      quantized: bool = False):
-    if quantized:
-        ks_ref, vs_ref, tq_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        tq_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(tbl_ref[pl.program_id(0), j] >= 0)
-    def _body():
-        cdt = jnp.bfloat16 if jnp.dtype(k_ref.dtype).itemsize == 1 \
-            else k_ref.dtype
-        q = q_ref[0, 0].astype(cdt)                    # (C*group, hd)
-        if quantized:
-            k = dequantize_kv(k_ref[0, :, 0], ks_ref[0, :, 0])  # (bl, hd)
-            v = dequantize_kv(v_ref[0, :, 0], vs_ref[0, :, 0])
-        else:
-            k = k_ref[0, :, 0].astype(cdt)             # (bl, hd)
-            v = v_ref[0, :, 0].astype(cdt)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        pos = pos_ref[0]                               # (bl,) int32
-        tq = tq_ref[0]                                 # (C*group,) int32
-        valid = (pos[None, :] >= 0) & (pos[None, :] <= tq[:, None])
-        if window > 0:
-            valid &= pos[None, :] > tq[:, None] - window
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(cdt), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(j == nT - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def gqa_paged_chunk_p(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -497,112 +535,27 @@ def gqa_paged_chunk_p(q: jax.Array, k: jax.Array, v: jax.Array,
                       interpret: bool | None = None) -> jax.Array:
     """Fused paged GQA chunk prefill (C > 1 query tokens per row).
 
-    q: (B, C, H, hd); k/v: arenas (n_blocks, block_len, Hkv, hd); pos:
+    q: (B, C, H, hd); k/v: arenas (n_blocks, Hkv, block_len, hd); pos:
     (B, T*block_len); t: (B, C) per-query positions (< 0 = pad); table:
     (B, T). Returns (B, C, H*hd) in q's dtype.
 
     Same grid/DMA story as :func:`gqa_paged_p` — the chunk folds into
     the query-row axis (query token c, group member g -> row c*group+g)
-    and ``t`` expands to a per-row position vector, so each chunk token
+    and ``t`` expands to a per-row position column, so each chunk token
     masks against its own causal frontier inside one online-softmax
     pass over the row's arena blocks. ``k_scale``/``v_scale``: int8
     scale arenas as in :func:`gqa_paged_p`."""
     B, C, H, hd = q.shape
-    Hkv = k.shape[2]
+    Hkv = k.shape[1]
     group = H // Hkv
-    bl = k.shape[1]
-    T = table.shape[1]
     CG = C * group
     qf = (q.reshape(B, C, Hkv, group, hd).transpose(0, 2, 1, 3, 4)
           .reshape(B, Hkv, CG, hd))
-    tq = jnp.repeat(t.astype(jnp.int32), group, axis=1)      # (B, CG)
-    quantized = k_scale is not None
-    kern = functools.partial(_gqa_chunk_kernel, scale=hd ** -0.5,
-                             window=window, nT=T, quantized=quantized)
-    kv_spec = pl.BlockSpec(
-        (1, bl, 1, hd),
-        lambda b, h, j, tbl: (jnp.maximum(tbl[b, j], 0), 0, h, 0))
-    sc_spec = pl.BlockSpec(
-        (1, bl, 1),
-        lambda b, h, j, tbl: (jnp.maximum(tbl[b, j], 0), 0, h))
-    in_specs = [
-        pl.BlockSpec((1, 1, CG, hd), lambda b, h, j, tbl: (b, h, 0, 0)),
-        kv_spec, kv_spec,
-        *([sc_spec, sc_spec] if quantized else []),
-        pl.BlockSpec((1, CG), lambda b, h, j, tbl: (b, 0)),
-        pl.BlockSpec((1, bl), lambda b, h, j, tbl: (b, j)),
-    ]
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                      # table
-        grid=(B, Hkv, T),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, CG, hd),
-                               lambda b, h, j, tbl: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((CG, 1), jnp.float32),
-            pltpu.VMEM((CG, 1), jnp.float32),
-            pltpu.VMEM((CG, hd), jnp.float32),
-        ],
-    )
-    args = (qf, k, v) + ((k_scale, v_scale) if quantized else ()) \
-        + (tq, pos)
-    o = pl.pallas_call(
-        kern, grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, CG, hd), q.dtype),
-        interpret=_interpret(interpret),
-    )(table.astype(jnp.int32), *args)
+    tq = jnp.repeat(t, group, axis=1)                        # (B, CG)
+    o = _gqa_call(qf, k, v, pos, table, window=window, k_scale=k_scale,
+                  v_scale=v_scale, interpret=interpret, tq=tq)
     return (o.reshape(B, Hkv, C, group, hd).transpose(0, 2, 1, 3, 4)
             .reshape(B, C, H * hd))
-
-
-def _mla_chunk_kernel(tbl_ref, qa_ref, qr_ref, c_ref, kr_ref, *rest,
-                      scale: float, nT: int, quantized: bool = False):
-    if quantized:
-        cs_ref, krs_ref, tq_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        tq_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
-        cs_ref = krs_ref = None
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(tbl_ref[pl.program_id(0), j] >= 0)
-    def _body():
-        if quantized:
-            c = dequantize_kv(c_ref[0], cs_ref[0])     # (bl, kvr) bf16
-            kr = dequantize_kv(kr_ref[0], krs_ref[0])  # (bl, rope_d)
-        else:
-            c = c_ref[0]                               # (bl, kvr)
-            kr = kr_ref[0]                             # (bl, rope_d)
-        cdt = c.dtype
-        qa = qa_ref[0].astype(cdt)                     # (C*H, kvr)
-        qr = qr_ref[0].astype(kr.dtype)                # (C*H, rope_d)
-        s = jax.lax.dot_general(qa, c, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        s = s * scale
-        pos = pos_ref[0]
-        tq = tq_ref[0]                                 # (C*H,)
-        valid = (pos[None, :] >= 0) & (pos[None, :] <= tq[:, None])
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(cdt), c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(j == nT - 1)
-    def _done():
-        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 def mla_paged_chunk_p(q_abs: jax.Array, q_rope: jax.Array, c: jax.Array,
@@ -621,47 +574,10 @@ def mla_paged_chunk_p(q_abs: jax.Array, q_rope: jax.Array, c: jax.Array,
     :func:`mla_paged_p`. ``c_scale``/``kr_scale``: int8 scale arenas
     (n_blocks, block_len)."""
     B, C, H, kvr = q_abs.shape
-    rope_d = q_rope.shape[-1]
-    bl = c.shape[1]
-    T = table.shape[1]
     CH = C * H
-    qaf = q_abs.reshape(B, CH, kvr)
-    qrf = q_rope.reshape(B, CH, rope_d)
-    tq = jnp.repeat(t.astype(jnp.int32), H, axis=1)          # (B, CH)
-    quantized = c_scale is not None
-    kern = functools.partial(_mla_chunk_kernel, scale=scale, nT=T,
-                             quantized=quantized)
-    sc_spec = pl.BlockSpec(
-        (1, bl), lambda b, j, tbl: (jnp.maximum(tbl[b, j], 0), 0))
-    in_specs = [
-        pl.BlockSpec((1, CH, kvr), lambda b, j, tbl: (b, 0, 0)),
-        pl.BlockSpec((1, CH, rope_d), lambda b, j, tbl: (b, 0, 0)),
-        pl.BlockSpec((1, bl, kvr),
-                     lambda b, j, tbl: (jnp.maximum(tbl[b, j], 0),
-                                        0, 0)),
-        pl.BlockSpec((1, bl, rope_d),
-                     lambda b, j, tbl: (jnp.maximum(tbl[b, j], 0),
-                                        0, 0)),
-        *([sc_spec, sc_spec] if quantized else []),
-        pl.BlockSpec((1, CH), lambda b, j, tbl: (b, 0)),
-        pl.BlockSpec((1, bl), lambda b, j, tbl: (b, j)),
-    ]
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, T),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, CH, kvr), lambda b, j, tbl: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((CH, 1), jnp.float32),
-            pltpu.VMEM((CH, 1), jnp.float32),
-            pltpu.VMEM((CH, kvr), jnp.float32),
-        ],
-    )
-    args = (qaf, qrf, c, kr) \
-        + ((c_scale, kr_scale) if quantized else ()) + (tq, pos)
-    o = pl.pallas_call(
-        kern, grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((B, CH, kvr), jnp.float32),
-        interpret=_interpret(interpret),
-    )(table.astype(jnp.int32), *args)
+    tq = jnp.repeat(t, H, axis=1)                            # (B, CH)
+    o = _mla_call(q_abs.reshape(B, CH, kvr),
+                  q_rope.reshape(B, CH, q_rope.shape[-1]), c, kr, pos,
+                  table, scale=scale, c_scale=c_scale, kr_scale=kr_scale,
+                  interpret=interpret, tq=tq)
     return o.reshape(B, C, H, kvr)

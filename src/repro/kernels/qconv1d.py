@@ -4,11 +4,14 @@ Fuses depthwise(k) -> pointwise(CxC) -> (folded-BN scale+shift) -> ReLU,
 with int8 weights dequantised in VMEM.
 
 Tiling: grid (B,) — one basecalling chunk per grid step. A full chunk at
-RUBICALL sizes ((T=2048..4096) x C=344, fp32) is 2.8-5.6 MB, comfortably
-inside the ~128 MB VMEM budget, so the halo problem disappears: the
+RUBICALL sizes ((T=2048..4096) x C=344, fp32) is 2.8-5.6 MB, inside the
+128 MiB of a v5e core's VMEM, so the halo problem disappears: the
 depthwise conv is k shifted multiply-adds (VPU) over the in-VMEM chunk
 and the pointwise conv is one (T, C) x (C, C) MXU matmul. Weight HBM
-bytes ride at int8 — the RUBICALL-MP mixed-precision win.
+bytes ride at int8 — the RUBICALL-MP mixed-precision win. The chunk,
+its double buffers and the float32 temporaries outgrow the compiler's
+default 16 MiB scoped-VMEM window at a serving window's length, so the
+call raises that limit (``VMEM_LIMIT``).
 """
 from __future__ import annotations
 
@@ -17,6 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+VMEM_LIMIT = 96 * 2 ** 20      # of a v5e core's 128 MiB
 
 def _qconv_kernel(x_ref, dw_ref, pw_ref, dws_ref, pws_ref, g_ref, b_ref,
                   o_ref, *, k: int, relu: bool):
@@ -64,5 +70,6 @@ def qconv1d_block_p(x: jax.Array, dw_q: jax.Array, pw_q: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, T, C), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, C), x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(x, dw_q, pw_q, dw_scale, pw_scale, gamma, beta)

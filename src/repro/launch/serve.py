@@ -92,14 +92,23 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Dict, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.config import QuantPolicy, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.models.lm import transformer as tfm
+
+
+class ServeRun(NamedTuple):
+    """What an engine run leaves behind for an in-process caller."""
+    engine: Any
+    done: Dict[int, Any]          # rid -> completed request
+    warmup_s: float               # 0.0 without --warmup
 
 
 def quantize_for_serving(params, wbits: int):
@@ -252,7 +261,7 @@ def build_streamed_reads(cfg, args, seed: int = 0):
     return reads
 
 
-def run_streamed(engine, cfg, args) -> None:
+def run_streamed(engine, cfg, args) -> Dict[int, Any]:
     """Drive the engine from live StreamingRequests: submit each read at
     its Poisson start, then append samples as wall-clock time covers
     them (PORE_HZ per pore). Ejected reads stop appending — the forgone
@@ -311,6 +320,7 @@ def run_streamed(engine, cfg, args) -> None:
     if done:
         first = done[min(done)]
         print(f"[serve] sample ({first.status}):", first.out_tokens[:16])
+    return done
 
 
 def resolve_quant_policy(cfg, args):
@@ -339,7 +349,7 @@ def resolve_quant_policy(cfg, args):
     return spec
 
 
-def run_engine(params, cfg, args) -> None:
+def run_engine(params, cfg, args) -> ServeRun:
     if (args.stream or args.read_until) and cfg.family != "basecaller":
         raise SystemExit(
             f"[serve] error: --stream/--read-until serve live squiggle "
@@ -363,19 +373,21 @@ def run_engine(params, cfg, args) -> None:
         async_dispatch=args.async_dispatch, max_queue=args.max_queue,
         queue_timeout_s=args.queue_timeout, **runner_kw)
     basecall = cfg.family == "basecaller"
+    warmup_s = 0.0
     if args.warmup:
         t0 = time.perf_counter()
         n = engine.warmup()
+        warmup_s = time.perf_counter() - t0
         print(f"[serve] warmup: {n} tick plans pre-compiled in "
-              f"{time.perf_counter() - t0:.2f}s")
+              f"{warmup_s:.2f}s")
     if args.stream:
         print(f"[serve] engine ({type(engine.runner).__name__}): "
               f"{args.requests} LIVE reads (rate {args.rate}/s, "
               f"{PORE_HZ:.0f} samples/s per pore), {args.slots} slots, "
               f"chunk {engine.runner.core} samples (halo "
               f"{engine.runner.halo}), qos={args.qos}")
-        run_streamed(engine, cfg, args)
-        return
+        done = run_streamed(engine, cfg, args)
+        return ServeRun(engine, done, warmup_s)
     pending = build_request_stream(cfg, args)
     print(f"[serve] engine ({type(engine.runner).__name__}): "
           f"{args.requests} requests over "
@@ -460,6 +472,7 @@ def run_engine(params, cfg, args) -> None:
     if done:
         sample = done[min(done)].out_tokens[:16]
         print("[serve] sample:", sample)
+    return ServeRun(engine, done, warmup_s)
 
 
 def run_static(params, cfg, args) -> None:
@@ -531,7 +544,10 @@ def run_knob_search(params, cfg, args) -> None:
           f"{best.bytes_vs_bf16:.2f}x smaller than bf16)")
 
 
-def main():
+def main(argv: Optional[Sequence[str]] = None) -> Optional[ServeRun]:
+    """Parse ``argv`` (``sys.argv[1:]`` when None) and serve; engine
+    runs return a :class:`ServeRun` so in-process callers can check
+    the completed requests."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-4b")
     ap.add_argument("--smoke", action="store_true")
@@ -685,10 +701,14 @@ def main():
     ap.add_argument("--per-group", action="store_true",
                     help="knob search: add the coordinate-descent "
                          "per-group precision refinement pass")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if not args.cache_len:
         args.cache_len = args.prompt_len + args.tokens
 
+    cache_dir = enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[serve] device: {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())} | compile cache {cache_dir}")
     cfg = get_config(args.arch + ("-smoke" if args.smoke else ""))
     params = api.init_params(jax.random.key(0), cfg)
     if args.wbits:
@@ -705,7 +725,8 @@ def main():
     elif args.static:
         run_static(params, cfg, args)
     else:
-        run_engine(params, cfg, args)
+        return run_engine(params, cfg, args)
+    return None
 
 
 if __name__ == "__main__":

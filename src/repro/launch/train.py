@@ -20,6 +20,7 @@ import json
 import jax
 
 from repro.config import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.training.optimizer import AdamWConfig
 from repro.training.train_loop import TrainLoopConfig, run
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--num-hosts", type=int, default=1)
     ap.add_argument("--host-id", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.coordinator:
         jax.distributed.initialize(args.coordinator, args.num_hosts,

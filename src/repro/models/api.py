@@ -6,6 +6,7 @@ multi-pod dry-run lowers these without allocating anything).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
@@ -37,6 +38,14 @@ def init_params(rng, cfg: ModelConfig):
     if cfg.family == "basecaller":
         from repro.models.basecaller import model as bc
         return bc.init_params(rng, cfg)
+    return _init_lm_params(rng, cfg)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _init_lm_params(rng, cfg: ModelConfig):
+    """LM weights in ``cfg.dtype``. One program: each leaf is drawn in
+    float32 and cast inside it, so only the target-dtype tree is ever
+    held (a float32 copy of qwen1.5-4b alone would fill a v5e)."""
     from repro.models.lm import transformer as tfm
     params = tfm.init_decoder(rng, cfg)
     if cfg.family == "audio":

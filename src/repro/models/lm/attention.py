@@ -362,8 +362,9 @@ def attn_ring_len(cfg: ModelConfig, cache_len: int, *, window: int = 0) -> int:
 def init_attn_cache_paged(cfg: ModelConfig, n_slots: int, cache_len: int,
                           n_blocks: int, block_len: int, *, window: int = 0,
                           dtype=jnp.bfloat16) -> Dict:
-    """Paged slot-pool cache: KV bytes live in a shared block arena
-    ``(n_blocks, block_len, Hkv, hd)`` instead of one contiguous row per
+    """Paged slot-pool cache: KV bytes live in a shared heads-major block
+    arena ``(n_blocks, Hkv, block_len, hd)`` (one head's block is a whole
+    TPU tile for the fused kernel) instead of one contiguous row per
     slot. A host-side block table (``(n_slots, T)``, passed into the
     decode program each tick) maps each slot's logical block j to an
     arena block; positions stay PER SLOT (``pos: (n_slots, T*block_len)``
@@ -372,22 +373,22 @@ def init_attn_cache_paged(cfg: ModelConfig, n_slots: int, cache_len: int,
     the new occupant's ``pos`` row is empty until it writes.
 
     int8 ``dtype`` stores a QUANTIZED arena: K/V bytes are int8 and two
-    fp32 scale arenas (``k_scale``/``v_scale``, per block per position
-    per KV head) ride alongside, written at the same scatter indices as
-    their values."""
+    fp32 scale arenas (``k_scale``/``v_scale``, ``(n_blocks, Hkv,
+    block_len)``: per block per KV head per position) ride alongside,
+    written at the same scatter indices as their values."""
     Hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     L = attn_ring_len(cfg, cache_len, window=window)
     T = -(-L // block_len)                     # blocks per slot (ceil)
     cache = {
-        "k": jnp.zeros((n_blocks, block_len, Hkv, hd), dtype),
-        "v": jnp.zeros((n_blocks, block_len, Hkv, hd), dtype),
+        "k": jnp.zeros((n_blocks, Hkv, block_len, hd), dtype),
+        "v": jnp.zeros((n_blocks, Hkv, block_len, hd), dtype),
         "pos": jnp.full((n_slots, T * block_len), EMPTY_POS, jnp.int32),
         "window": jnp.asarray(window, jnp.int32),
     }
     if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
-        cache["k_scale"] = jnp.zeros((n_blocks, block_len, Hkv),
+        cache["k_scale"] = jnp.zeros((n_blocks, Hkv, block_len),
                                      jnp.float32)
-        cache["v_scale"] = jnp.zeros((n_blocks, block_len, Hkv),
+        cache["v_scale"] = jnp.zeros((n_blocks, Hkv, block_len),
                                      jnp.float32)
     return cache
 
@@ -424,7 +425,7 @@ def attn_decode_slots(p: Params, x: jax.Array, cache: Dict, t: jax.Array,
     query's position — ragged rows need no extra masking.
 
     ``table`` switches to the PAGED cache layout: ``cache["k"]``/``v``
-    are shared block arenas ``(n_blocks, block_len, Hkv, hd)`` and
+    are shared block arenas ``(n_blocks, Hkv, block_len, hd)`` and
     ``table: (B, T)`` int32 maps each row's logical block to an arena
     block (-1 = unassigned). Token position t lands in arena block
     ``table[b, (t % (T*block_len)) // block_len]`` at offset
@@ -461,8 +462,10 @@ def attn_decode_slots(p: Params, x: jax.Array, cache: Dict, t: jax.Array,
         o = decode_gqa(q, k, v, pos, t, window=window,
                        backend=attn_backend)
     else:
-        Nb, bl = cache["k"].shape[0], cache["k"].shape[1]
+        Nb, bl = cache["k"].shape[0], cache["k"].shape[2]
         wblk, off, lw, _, _ = paged_indices(table, t, Nb, bl)
+        # (B, C) block/offset indices around the head slice: the update
+        # operand is (B, C, Hkv, ...), exactly what the projection gives
         quantized = "k_scale" in cache
         if quantized:
             # int8 arena: quantize per token per KV head and scatter the
@@ -471,17 +474,17 @@ def attn_decode_slots(p: Params, x: jax.Array, cache: Dict, t: jax.Array,
             # bytes with a stale scale (or vice versa)
             kq, ks_new = quantize_kv(k_new)
             vq, vs_new = quantize_kv(v_new)
-            k = cache["k"].at[wblk, off].set(kq, mode="drop")
-            v = cache["v"].at[wblk, off].set(vq, mode="drop")
-            k_scale = cache["k_scale"].at[wblk, off].set(ks_new,
-                                                         mode="drop")
-            v_scale = cache["v_scale"].at[wblk, off].set(vs_new,
-                                                         mode="drop")
+            k = cache["k"].at[wblk, :, off].set(kq, mode="drop")
+            v = cache["v"].at[wblk, :, off].set(vq, mode="drop")
+            k_scale = cache["k_scale"].at[wblk, :, off].set(ks_new,
+                                                            mode="drop")
+            v_scale = cache["v_scale"].at[wblk, :, off].set(vs_new,
+                                                            mode="drop")
         else:
-            k = cache["k"].at[wblk, off].set(k_new.astype(cache["k"].dtype),
-                                             mode="drop")
-            v = cache["v"].at[wblk, off].set(v_new.astype(cache["v"].dtype),
-                                             mode="drop")
+            k = cache["k"].at[wblk, :, off].set(
+                k_new.astype(cache["k"].dtype), mode="drop")
+            v = cache["v"].at[wblk, :, off].set(
+                v_new.astype(cache["v"].dtype), mode="drop")
             k_scale = v_scale = None
         pos = cache["pos"].at[bidx, lw].set(t, mode="drop")
         o = decode_gqa(

@@ -49,10 +49,12 @@ def kernel_of(p: Params, dtype) -> jax.Array:
 def _qmatmul_tiles(m: int, k: int, n: int, bits: int) -> bool:
     """True when (M, K, N) satisfies ``qmatmul_p``'s tiling contract:
     every dim divides its ``min(128, dim)`` block, and int4 needs an
-    even K (two nibbles per byte along the reduction axis)."""
+    even K (two nibbles per byte along the reduction axis) that divides
+    its own K tile."""
     ok = all(d > 0 and d % min(128, d) == 0 for d in (m, k, n))
     if bits == 4:
-        ok = ok and k % 2 == 0 and min(128, k) % 2 == 0
+        from repro.kernels.qmatmul import int4_k_block
+        ok = ok and k % 2 == 0 and k % int4_k_block(k) == 0
     return ok
 
 
@@ -175,8 +177,8 @@ def _ambient_mesh() -> Optional[Any]:
             return pm
     except Exception:
         pass
-    from repro.compat import get_abstract_mesh
-    return get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:

@@ -406,10 +406,6 @@ tick shapes, int8 arenas included) and lints ``src/repro``, enforcing:
     reductions, no low-precision ``dot_general`` accumulators, and on
     quantized paths no fp32 downcast whose value reaches stats math.
     (bf16 QK/PV COMPUTE is the alignment contract and is exempt.)
-``compat``
-    Version-dependent JAX APIs (``get_abstract_mesh``, ``AxisType``,
-    ``make_mesh``) appear only inside ``repro/compat.py`` — everything
-    else imports the shims, keeping the 0.4.x floor pin honest.
 ``host-sync``
     ``np.asarray`` / ``.item()`` / ``device_get`` /
     ``block_until_ready`` inside engine/runner tick paths carry an

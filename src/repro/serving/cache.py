@@ -3,7 +3,8 @@
 Layout
 ------
 Per layer group, KV bytes live in a shared BLOCK ARENA: leaves of shape
-``(n_layers, n_blocks, block_len, ...)`` instead of one contiguous
+``(n_layers, n_blocks, ...)`` (GQA ``(n_blocks, Hkv, block_len, hd)``,
+MLA ``(n_blocks, block_len, ...)``) instead of one contiguous
 ``cache_len`` row per slot. A host-side block table per group
 (``(n_slots, T)`` int32, T = ceil(ring_len / block_len), -1 = free)
 maps each slot's logical block j to an arena block; the tables are tiny
@@ -50,7 +51,7 @@ Cache precision is a per-layer-group serving policy: each group stores
 its K/V (and MLA latent) leaves as ``bf16`` | ``fp8`` | ``int8``.
 ``fp8`` is a pure storage-dtype change (the kernels already compute in
 bf16 for 1-byte caches). ``int8`` adds fp32 SCALE LEAVES to the arena —
-``k_scale``/``v_scale`` of shape ``(n_blocks, block_len, Hkv)`` (MLA:
+``k_scale``/``v_scale`` of shape ``(n_blocks, Hkv, block_len)`` (MLA:
 ``c_scale``/``kr_scale`` at ``(n_blocks, block_len)``) — written at the
 SAME ``(wblk, off)`` indices as the K/V scatter, in the same jitted
 step, so a scale can never be newer or older than the bytes it scales.

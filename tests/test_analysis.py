@@ -31,8 +31,8 @@ SRC = Path(__file__).resolve().parents[1] / "src"
 
 def test_registry_has_the_five_rules():
     ids = [r.id for r in all_rules()]
-    assert ids == sorted(["no-materialization", "precision", "compat",
-                          "host-sync", "trace-stability"])
+    assert ids == sorted(["no-materialization", "precision", "host-sync",
+                          "trace-stability"])
 
 
 def test_registry_rejects_unknown_rule():
@@ -56,7 +56,7 @@ def test_walker_descends_into_pjit_and_scan():
     (gsite,) = [s for s in iter_eqns(jx)
                 if s.eqn.primitive.name == "gather"]
     # provenance: jnp.take nests its clipping helper inside the jit
-    assert gsite.path[0] == "pjit"
+    assert gsite.path[0] == "jit"
     assert gsite.path_str.endswith("/gather")
     assert gather_sizes(jx) == [2 * 4]
 
@@ -165,37 +165,6 @@ def test_precision_accepts_the_dequant_contract():
                                        quantized=True, arena_sigs={})) == []
 
 
-# ---------------------------------------------------------- rule: compat
-
-
-_COMPAT_BAD = "import jax\nmesh = jax.sharding.get_abstract_mesh()\n"
-
-
-def test_compat_flags_raw_api_outside_compat_py():
-    from repro.analysis.rules.compat_gate import check_source
-    (f,) = check_source("launch/mesh.py", _COMPAT_BAD)
-    assert f.rule == "compat"
-    assert f.where == "launch/mesh.py:2"      # provenance: exact line
-    assert "get_abstract_mesh" in f.message
-
-    (f2,) = check_source(
-        "models/x.py", "from jax.sharding import AxisType\n")
-    assert f2.where == "models/x.py:1" and "AxisType" in f2.message
-
-    (f3,) = check_source(
-        "models/y.py",
-        "import jax\ng = getattr(jax.sharding, 'get_abstract_mesh', None)\n")
-    assert "getattr" in f3.message
-
-
-def test_compat_exempts_compat_py_and_inline_allow():
-    from repro.analysis.rules.compat_gate import check_source
-    assert check_source("compat.py", _COMPAT_BAD) == []
-    allowed = ("import jax\n"
-               "m = jax.sharding.get_abstract_mesh()  # repro-allow: compat\n")
-    assert check_source("launch/mesh.py", allowed) == []
-
-
 # ------------------------------------------------------- rule: host-sync
 
 
@@ -255,53 +224,53 @@ def test_trace_stability_accepts_stable_program():
 
 
 def test_allowlist_suppression_globs():
-    f = Finding("compat", "launch/mesh.py:2", "msg")
-    assert is_allowed(f, ["compat:launch/*"])
-    assert is_allowed(f, ["compat"])          # bare rule = everywhere
-    assert not is_allowed(f, ["precision:launch/*"])
-    kept, supp = apply_allowlist([f], ["compat:launch/*"])
+    f = Finding("host-sync", "serving/runner.py:6", "msg")
+    assert is_allowed(f, ["host-sync:serving/*"])
+    assert is_allowed(f, ["host-sync"])       # bare rule = everywhere
+    assert not is_allowed(f, ["precision:serving/*"])
+    kept, supp = apply_allowlist([f], ["host-sync:serving/*"])
     assert kept == [] and supp == [f]
 
 
 def test_inline_allow_matches_rule_list():
-    lines = ["x = 1  # repro-allow: compat, host-sync"]
-    assert inline_allowed(lines, 1, "compat")
+    lines = ["x = 1  # repro-allow: trace-stability, host-sync"]
+    assert inline_allowed(lines, 1, "trace-stability")
     assert inline_allowed(lines, 1, "host-sync")
     assert not inline_allowed(lines, 1, "precision")
 
 
 def test_driver_reports_crashed_rule_as_finding(monkeypatch):
-    import repro.analysis.rules.compat_gate as cg
+    import repro.analysis.rules.host_sync as hs
     monkeypatch.setattr(
-        cg, "check_source",
+        hs, "check_source",
         lambda *a, **k: (_ for _ in ()).throw(RuntimeError("boom")))
     ctx = AnalysisContext()
-    (f,) = [f for f in run_rules(ctx, ["compat"]) if f.rule == "compat"]
-    assert f.where == "rule:compat" and "crashed" in f.message
+    (f,) = [f for f in run_rules(ctx, ["host-sync"])
+            if f.rule == "host-sync"]
+    assert f.where == "rule:host-sync" and "crashed" in f.message
 
 
 def test_cli_nonzero_on_seeded_tree_and_allow_flag(tmp_path, capsys):
-    bad = tmp_path / "launch"
-    bad.mkdir()
-    (bad / "mesh.py").write_text(_COMPAT_BAD)
     (tmp_path / "serving").mkdir()
     (tmp_path / "serving" / "runner.py").write_text(
         _SYNC_SNIPPET.format(marker=""))
+    (tmp_path / "serving" / "engine.py").write_text(
+        _SYNC_SNIPPET.format(marker=""))
 
-    rc = main(["--rules", "compat,host-sync", "--root", str(tmp_path)])
+    rc = main(["--rules", "host-sync", "--root", str(tmp_path)])
     out = capsys.readouterr().out
     assert rc == 1
-    assert "launch/mesh.py:2" in out and "serving/runner.py:6" in out
+    assert "serving/engine.py:6" in out and "serving/runner.py:6" in out
 
-    rc = main(["--rules", "compat,host-sync", "--root", str(tmp_path),
-               "--allow", "compat:launch/*",
-               "--allow", "host-sync:serving/*"])
+    rc = main(["--rules", "host-sync", "--root", str(tmp_path),
+               "--allow", "host-sync:serving/engine.py:*",
+               "--allow", "host-sync:serving/runner.py:*"])
     assert rc == 0
     assert "suppressed" in capsys.readouterr().out
 
 
 def test_cli_ast_rules_clean_on_repo():
-    assert main(["--rules", "compat,host-sync"]) == 0
+    assert main(["--rules", "host-sync"]) == 0
 
 
 def test_driver_flags_seeded_jaxpr_targets_through_registry():

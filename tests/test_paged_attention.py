@@ -108,6 +108,12 @@ def test_paged_indices_recycled_block_lockstep():
 # ------------------------------------------------- kernel numeric parity
 
 
+def _heads_major(arena):
+    """(n_blocks, block_len, Hkv, hd) as built -> the arena layout
+    (n_blocks, Hkv, block_len, hd)."""
+    return jnp.asarray(arena.transpose(0, 2, 1, 3))
+
+
 def _mk_paged(rs, B, Hkv, hd, bl, T, n_blocks, poison=99.0):
     """Random arena with poisoned bytes everywhere (every block is
     'recycled'), a random table and per-row fill levels."""
@@ -131,7 +137,7 @@ def _mk_paged(rs, B, Hkv, hd, bl, T, n_blocks, poison=99.0):
             k[blk, off] = rs.randn(Hkv, hd)
             v[blk, off] = rs.randn(Hkv, hd)
             pos[b, p] = p
-    return (jnp.asarray(k), jnp.asarray(v), jnp.asarray(pos),
+    return (_heads_major(k), _heads_major(v), jnp.asarray(pos),
             jnp.asarray(t), jnp.asarray(table))
 
 
@@ -217,8 +223,8 @@ def test_mla_fused_matches_reference(bl, T):
     B, H, kvr, rope_d = 4, 4, 16, 8
     n_blocks = B * T + 2
     c, kr, pos, t, table = _mk_paged(rs, B, 1, kvr, bl, T, n_blocks)
-    c, kr = c[:, :, 0], jnp.asarray(
-        np.asarray(kr)[:, :, 0, :rope_d].copy())
+    c, kr = c[:, 0], jnp.asarray(
+        np.asarray(kr)[:, 0, :, :rope_d].copy())
     qa = jnp.asarray(rs.randn(B, 1, H, kvr), jnp.float32)
     qr = jnp.asarray(rs.randn(B, 1, H, rope_d), jnp.float32)
     ref = ops.decode_mla(qa, qr, c, kr, pos, t, scale=0.17, table=table,
@@ -259,7 +265,7 @@ def _mk_paged_chunk(rs, B, Hkv, hd, bl, T, n_blocks, C, fills,
             k[blk, off] = rs.randn(Hkv, hd)
             v[blk, off] = rs.randn(Hkv, hd)
             pos[b, p] = p
-    return (jnp.asarray(k), jnp.asarray(v), jnp.asarray(pos),
+    return (_heads_major(k), _heads_major(v), jnp.asarray(pos),
             jnp.asarray(t), jnp.asarray(table))
 
 
@@ -345,8 +351,8 @@ def test_mla_chunk_fused_matches_reference(bl, T, C):
     c, kr, pos, t, table = _mk_paged_chunk(rs, B, 1, kvr, bl, T,
                                            B * T + 2, C,
                                            _chunk_fills(bl, T, C))
-    c, kr = c[:, :, 0], jnp.asarray(
-        np.asarray(kr)[:, :, 0, :rope_d].copy())
+    c, kr = c[:, 0], jnp.asarray(
+        np.asarray(kr)[:, 0, :, :rope_d].copy())
     qa = jnp.asarray(rs.randn(B, C, H, kvr), jnp.float32)
     qr = jnp.asarray(rs.randn(B, C, H, rope_d), jnp.float32)
     ref = ops.decode_mla(qa, qr, c, kr, pos, t, scale=0.17, table=table,
@@ -511,3 +517,16 @@ def test_interpret_resolved_at_call_time(monkeypatch):
     for fn in (ops._qmatmul_jit, ops._flash_attention_jit,
                ops._qconv1d_block_jit, ops._ssd_chunk_scan_jit):
         assert isinstance(fn, jitted)
+
+
+def test_interpret_refused_on_tpu(monkeypatch):
+    """On a TPU the kernels always compile: REPRO_PALLAS_INTERPRET
+    asking for the interpreter is an error, never a silent slow path."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert ops.interpret_default() is False
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert ops.interpret_default() is False
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
+        ops.interpret_default()

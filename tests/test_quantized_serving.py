@@ -150,19 +150,20 @@ def _mk_paged_q(rs, B, Hkv, hd, bl, T, n_blocks, C=1, mode="int8",
             pos[b, p] = p
     if mode == "fp8":
         dt = jnp.float8_e4m3fn
-        k = jnp.asarray(kf).astype(dt)
-        v = jnp.asarray(vf).astype(dt)
-        k = jnp.where(jnp.asarray(written)[..., None, None], k,
+        k = jnp.asarray(kf.transpose(0, 2, 1, 3)).astype(dt)
+        v = jnp.asarray(vf.transpose(0, 2, 1, 3)).astype(dt)
+        k = jnp.where(jnp.asarray(written)[:, None, :, None], k,
                       jnp.asarray(99.0, dt))
         return (k, v, None, None, jnp.asarray(pos), jnp.asarray(t),
                 jnp.asarray(table))
-    kq, ks = quantize_kv(jnp.asarray(kf))
-    vq, vs = quantize_kv(jnp.asarray(vf))
-    w = jnp.asarray(written)
-    kq = jnp.where(w[..., None, None], kq, jnp.asarray(103, jnp.int8))
-    vq = jnp.where(w[..., None, None], vq, jnp.asarray(-91, jnp.int8))
-    ks = jnp.where(w[..., None], ks, scale_poison)    # stale-scale traps
-    vs = jnp.where(w[..., None], vs, scale_poison)
+    # heads-major arena layout: (n_blocks, Hkv, block_len, hd)
+    kq, ks = quantize_kv(jnp.asarray(kf.transpose(0, 2, 1, 3)))
+    vq, vs = quantize_kv(jnp.asarray(vf.transpose(0, 2, 1, 3)))
+    w = jnp.asarray(written)[:, None, :]
+    kq = jnp.where(w[..., None], kq, jnp.asarray(103, jnp.int8))
+    vq = jnp.where(w[..., None], vq, jnp.asarray(-91, jnp.int8))
+    ks = jnp.where(w, ks, scale_poison)               # stale-scale traps
+    vs = jnp.where(w, vs, scale_poison)
     return kq, vq, ks, vs, jnp.asarray(pos), jnp.asarray(t), jnp.asarray(table)
 
 
@@ -240,9 +241,9 @@ def test_mla_int8_fused_matches_reference(bl, T, C):
     B, H, kvr, rope_d = 4, 4, 16, 8
     cq, krq, cs, krs, pos, t, table = _mk_paged_q(rs, B, 1, kvr, bl, T,
                                                   B * T + 2, C)
-    cq, cs = cq[:, :, 0], cs[:, :, 0]
-    krq = jnp.asarray(np.asarray(krq)[:, :, 0, :rope_d].copy())
-    krs_full = krs[:, :, 0]
+    cq, cs = cq[:, 0], cs[:, 0]
+    krq = jnp.asarray(np.asarray(krq)[:, 0, :, :rope_d].copy())
+    krs_full = krs[:, 0]
     # kr is quantized over its own rope_d slice in the real cache; re-do
     krq2, krs2 = quantize_kv(dequantize_kv(krq, krs_full, jnp.float32))
     qa = jnp.asarray(rs.randn(B, C, H, kvr), jnp.float32)

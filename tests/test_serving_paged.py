@@ -129,10 +129,13 @@ def test_paged_attn_matches_contiguous_layout():
     table = np.array([[2, 4], [1, 3]], np.int32)
     karena = jnp.full_like(paged["k"], 99.0)    # poison unwritten bytes
     varena = jnp.full_like(paged["v"], 99.0)
-    karena = karena.at[2].set(kv[0, 0, 0:4]).at[4, 0:2].set(kv[0, 0, 4:6])
-    varena = varena.at[2].set(kv[1, 0, 0:4]).at[4, 0:2].set(kv[1, 0, 4:6])
-    karena = karena.at[1].set(kv[0, 1, 0:4])
-    varena = varena.at[1].set(kv[1, 1, 0:4])
+    hm = lambda a: jnp.swapaxes(a, 0, 1)        # (bl, Hkv, hd) -> arena
+    karena = karena.at[2].set(hm(kv[0, 0, 0:4])).at[4, :, 0:2].set(
+        hm(kv[0, 0, 4:6]))
+    varena = varena.at[2].set(hm(kv[1, 0, 0:4])).at[4, :, 0:2].set(
+        hm(kv[1, 0, 4:6]))
+    karena = karena.at[1].set(hm(kv[0, 1, 0:4]))
+    varena = varena.at[1].set(hm(kv[1, 1, 0:4]))
     paged = {**paged, "k": karena, "v": varena, "pos": jnp.asarray(pos)}
 
     x = jax.random.normal(key, (B, 1, cfg.d_model), jnp.float32)
@@ -147,9 +150,9 @@ def test_paged_attn_matches_contiguous_layout():
     # writes landed in the mapped arena blocks: row 0 pos 6 -> logical
     # block 1 -> arena block 4, offset 2; row 1 pos 4 -> arena block 3,
     # offset 0; untouched block 0 keeps its poison
-    np.testing.assert_allclose(np.asarray(nc_p["k"][4, 2]),
+    np.testing.assert_allclose(np.asarray(nc_p["k"][4, :, 2]),
                                np.asarray(nc_c["k"][0, 6]), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(nc_p["k"][3, 0]),
+    np.testing.assert_allclose(np.asarray(nc_p["k"][3, :, 0]),
                                np.asarray(nc_c["k"][1, 4]), rtol=1e-6)
     assert (np.asarray(nc_p["k"][0]) == 99.0).all()
 
