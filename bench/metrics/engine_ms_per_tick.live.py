@@ -1,0 +1,12 @@
+"""Host self time of ``engine.step`` per dispatched tick, outside the
+runner's dispatch and collect, plus the traffic driver's appends into
+``StreamingRequest``."""
+
+
+def read(ctx):
+    s, ticks = ctx["spans"], ctx["counters"]["ticks"]
+    if not ticks:
+        return None
+    own = (s.total_s("step") - s.total_s("dispatch") - s.total_s("collect")
+           + s.total_s("append"))
+    return own / ticks * 1e3
