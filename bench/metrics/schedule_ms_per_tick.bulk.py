@@ -1,0 +1,8 @@
+"""Host time of the engine's admission and scheduling (the program's
+``serving.admit`` and ``serving.schedule`` spans) per dispatched tick."""
+from bench import program_spans
+
+
+def read(ctx):
+    return program_spans.ms_per_tick(ctx, ("serving.admit",
+                                         "serving.schedule"))

@@ -57,15 +57,19 @@ def forward(params: Params, state: State, signal: jax.Array,
     for i in range(cfg.n_blocks):
         gate = None if skip_gates is None else skip_gates[i]
         dilation = 2 ** (i % 5) if causal else 1
-        x, ns = bl.block_forward(params[f"block{i:02d}"],
-                                 state[f"block{i:02d}"], x, cfg, i,
-                                 train=train, skip_gate=gate,
-                                 dilation=dilation, causal=causal,
-                                 bounds=bounds, s_in=s_in)
+        # the scope names each block's device ops in profiler traces
+        with jax.named_scope(f"block{i:02d}"):
+            x, ns = bl.block_forward(params[f"block{i:02d}"],
+                                     state[f"block{i:02d}"], x, cfg, i,
+                                     train=train, skip_gate=gate,
+                                     dilation=dilation, causal=causal,
+                                     bounds=bounds, s_in=s_in)
         new_state[f"block{i:02d}"] = ns
         s_in *= int(cfg.strides[i])
-    logits = bl.conv1d(x, bl.conv_kernel_of(params["head_pw"], x.dtype))
-    return jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1), new_state
+    with jax.named_scope("head"):
+        logits = bl.conv1d(x, bl.conv_kernel_of(params["head_pw"], x.dtype))
+        log_probs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    return log_probs, new_state
 
 
 # ---------------------------------------------------------------------------

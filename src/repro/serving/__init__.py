@@ -326,6 +326,18 @@ the bound and are never shed. The metrics summary books
 ``idle_ticks`` and the plan-cache counters; ``serve.py`` prints them
 as the dispatch report.
 
+Spans (``repro.serving.tracing``)
+---------------------------------
+
+While a profiler session collects (``jax.profiler.start_trace`` or a
+capture through ``start_server``), the engine and the basecaller
+runner record spans — ``serving.tick``, ``admit``, ``schedule``,
+``dispatch``, ``device_wait``, ``readback``, ``ctc_merge``, ``book``,
+and per request ``window_wait`` and ``verdict`` — as profiler
+annotations beside the device ops and in a bounded in-memory ring
+(``tracing.default()``). With no session a span costs one check.
+Request counters stay in ``ServingMetrics``.
+
 Migration note (PR 4)
 ---------------------
 

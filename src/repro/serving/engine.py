@@ -18,6 +18,7 @@ from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence)
 
 import numpy as np
 
+from repro.serving import tracing
 from repro.serving.metrics import ServingMetrics
 from repro.serving.runner import (Chunk, DecodeWork, PrefillWork,
                                   make_runner)
@@ -173,6 +174,8 @@ class _Slot:
                                        # this slot (token not read back yet)
     eject_pending: bool = False        # eject once the speculative tick
                                        # in flight is harvested+discarded
+    decided_at: Optional[float] = None  # enable time of the window whose
+                                        # read-until verdict said eject
 
 
 class ServingEngine:
@@ -249,6 +252,9 @@ class ServingEngine:
     history_limit : bound host-side growth for indefinite serves (slot
         history, completed map, metrics reservoirs roll; aggregate
         counters stay exact). None = unbounded (tests, benches).
+    clock : the metrics' and recorded spans' clock (tests pass a fake).
+    tracer : span recorder (:mod:`repro.serving.tracing`); None = the
+        process-wide ``tracing.default()``, also handed to the runner.
     runner : pre-built ModelRunner (overrides the registry dispatch).
     **runner_kw : extra backend knobs, e.g. ``chunk_samples``/``beam``/
         ``model_state`` for the basecaller runner.
@@ -262,10 +268,12 @@ class ServingEngine:
                  cache_dtype=None, block_len: int = 0,
                  n_blocks: int = 0, history_limit: Optional[int] = None,
                  clock: Callable[[], float] = time.perf_counter,
+                 tracer: Optional[tracing.Tracer] = None,
                  runner=None, **runner_kw):
         if cache_dtype is None:
             import jax.numpy as jnp   # local: engine itself is model-free
             cache_dtype = jnp.bfloat16
+        self.tracer = tracer if tracer is not None else tracing.default()
         self.params = params
         self.cfg = cfg
         self.n_slots = int(n_slots)
@@ -277,7 +285,8 @@ class ServingEngine:
             params, cfg, n_slots=self.n_slots, cache_len=self.cache_len,
             prefill_chunk=self.prefill_chunk, cache_dtype=cache_dtype,
             block_len=block_len, n_blocks=n_blocks,
-            async_dispatch=bool(async_dispatch), **runner_kw)
+            async_dispatch=bool(async_dispatch), tracer=self.tracer,
+            **runner_kw)
         self.async_dispatch = bool(async_dispatch)
         self.max_queue = int(max_queue)
         self.queue_timeout_s = float(queue_timeout_s)
@@ -292,9 +301,10 @@ class ServingEngine:
                     f"support; {type(self.runner).__name__} is "
                     f"synchronous-only")
         # the one in-flight tick under async dispatch:
-        # [works, handle, discard-slot set, per-slot stream (need,
-        #  needs_finish) metadata] — harvested one step later
+        # [works, handle, discard-slot set, per-slot enable times of
+        #  streamed windows] — harvested one step later
         self._inflight: Optional[list] = None
+        self._ticks = 0                 # dispatched ticks (trace step)
         self._last_idle_sig = None      # idle-tick fast path witness
         self.history_limit = history_limit
         self.metrics = ServingMetrics(clock, max_samples=history_limit)
@@ -386,7 +396,9 @@ class ServingEngine:
         dispatch + deferred harvest when ``async_dispatch``)."""
         t0 = self.metrics.clock()
         self._shed_expired()
-        self._admit()
+        if self.queue:
+            with self.tracer.span("admit"):
+                self._admit()
         sig = self._idle_signature()
         if sig is not None and sig == self._last_idle_sig:
             # idle fast path: every live slot is a stream still waiting
@@ -397,9 +409,10 @@ class ServingEngine:
         if self.async_dispatch:
             dispatched = self._step_async()
         elif self.co_batch:
-            if self.runner.autoregressive:
-                self._ensure_decode_blocks()
-            works = self._schedule()
+            with self.tracer.span("schedule"):
+                if self.runner.autoregressive:
+                    self._ensure_decode_blocks()
+                works = self._schedule()
             dispatched = any(w is not None for w in works)
             self._run_works(works)
         else:
@@ -458,18 +471,26 @@ class ServingEngine:
         slot that emitted in the still-in-flight tick) rides as a
         CHAINED decode row, resolved on device. Returns True when
         device work was dispatched."""
-        if self.runner.autoregressive:
-            self._ensure_decode_blocks()
-        works = self._schedule(async_=True)
+        with self.tracer.span("schedule"):
+            if self.runner.autoregressive:
+                self._ensure_decode_blocks()
+            works = self._schedule(async_=True)
         prev, self._inflight = self._inflight, None
-        if any(w is not None for w in works):
-            meta = self._stream_meta(works)
-            self._book_dispatch(works)
+        rows = sum(w is not None for w in works)
+        if not rows:
+            if prev is not None:
+                self._harvest(prev)
+            return False
+        with self._tick_span(rows):
+            enabled = self._enable_times(works)
+            with self.tracer.span("book"):
+                self._book_dispatch(works)
+            self._record_window_waits(enabled)
             handle = self.runner.dispatch(works)
-            self._inflight = [works, handle, set(), meta]
-        if prev is not None:
-            self._harvest(prev)
-        return self._inflight is not None
+            self._inflight = [works, handle, set(), enabled]
+            if prev is not None:
+                self._harvest(prev)
+        return True
 
     def flush(self) -> None:
         """Harvest the in-flight tick, if any. After a flush every
@@ -479,16 +500,59 @@ class ServingEngine:
         if prev is not None:
             self._harvest(prev)
 
-    def _stream_meta(self, works) -> List[Optional[tuple]]:
-        """Capture each streaming work's (need, needs_finish) enabling
-        event AT DISPATCH — by harvest time the cursor may already have
-        issued the next window and overwritten the slot's copy."""
-        meta: List[Optional[tuple]] = [None] * self.n_slots
+    def _tick_span(self, rows: int):
+        """``serving.tick`` around one dispatching tick, numbered."""
+        self._ticks += 1
+        return self.tracer.span("tick", step_num=self._ticks - 1, rows=rows)
+
+    def _enable_times(self, works) -> List[Optional[float]]:
+        """Per slot, the clock time of the event that made its streamed
+        window coverable (None for other rows), taken AT DISPATCH — by
+        harvest time the cursor may already have issued the next window
+        and overwritten the slot's (need, needs_finish)."""
+        out: List[Optional[float]] = [None] * self.n_slots
         for i, w in enumerate(works):
             s = self.slots[i]
             if isinstance(w, PrefillWork) and s.stream is not None:
-                meta[i] = (s.stream.need, s.stream.needs_finish)
-        return meta
+                out[i] = s.req.enable_time(s.stream.need,
+                                           s.stream.needs_finish)
+        return out
+
+    def _record_window_waits(self, enabled) -> None:
+        """``serving.window_wait``: each streamed window's wait from its
+        enabling event to this dispatch."""
+        now = self.metrics.clock()
+        for i, t_en in enumerate(enabled):
+            if t_en is not None:
+                self.tracer.record("window_wait", t_en, now,
+                                   rid=self.slots[i].req.rid, slot=i)
+
+    def _book_emit(self, t_en: Optional[float]) -> None:
+        """Emit latency of one harvested streamed window: from its
+        enabling event to its bases (if any) landing in ``out_tokens``."""
+        if t_en is not None:
+            self.metrics.record_emit(max(self.metrics.clock() - t_en, 0.0))
+
+    def _book_ejections(self, enabled) -> None:
+        """Read-until verdicts surface after the tick's tokens are
+        booked (a read finishing this very tick wins over its ejection —
+        its _finish already reset the row, clearing the pending
+        verdict). A slot with a newer window in flight (async) is
+        ejected at that window's harvest, its output discarded."""
+        pop = getattr(self.runner, "pop_ejections", None)
+        if pop is None:
+            return
+        for i in pop():
+            s = self.slots[i]
+            if s.state == FREE or s.req is None or s.req.done:
+                continue
+            s.decided_at = enabled[i]
+            if self._inflight is not None \
+                    and self._inflight[0][i] is not None:
+                s.eject_pending = True
+                self._inflight[2].add(i)
+            else:
+                self._eject(i)
 
     def _book_dispatch(self, works) -> None:
         """Dispatch-time booking: every host-deterministic transition
@@ -509,7 +573,6 @@ class ServingEngine:
                 slot.fresh = False
                 slot.pos += w.n_units
                 slot.inflight_emit = False
-                self.metrics.record_prefill(w.n_units)
                 if slot.stream is not None:
                     slot.stream.consumed = slot.pos
                 if not w.final:
@@ -535,67 +598,51 @@ class ServingEngine:
         speculative tick was already in flight park in DRAIN and
         resolve here one tick later, their speculative output
         discarded."""
-        works, handle, discard, meta = inflight
+        works, handle, discard, enabled = inflight
         n_decode = sum(isinstance(w, DecodeWork) for w in works)
         t0 = self.metrics.clock()
         # sync: the tick's one deferred readback — collect() returns
         # the emitted tokens to the host, a full tick behind dispatch
         emitted = self.runner.collect(handle, discard=frozenset(discard))
         dt = self.metrics.clock() - t0
-        if n_decode:
-            self.metrics.record_decode(n_decode, dt)
-        for i, w in enumerate(works):
-            if w is None:
-                continue
-            slot = self.slots[i]
-            if i in discard:
-                # post-completion speculative work: its token was
-                # dropped in collect; resolve the slot the way the
-                # earlier harvest decided
-                if slot.eject_pending:
-                    self._eject(i)
-                elif slot.state == DRAIN:
-                    self._finish(i)
-                continue
-            toks = [int(x) for x in emitted[i]]
-            if isinstance(w, PrefillWork):
-                if slot.stream is not None and toks and meta[i] is not None:
-                    t_en = slot.req.enable_time(*meta[i])
-                    if t_en is not None:
-                        self.metrics.record_emit(
-                            max(self.metrics.clock() - t_en, 0.0))
-                if toks:
-                    first = not slot.req.out_tokens
-                    slot.req.out_tokens.extend(toks)
-                    if first:
-                        self.metrics.record_first_token(slot.req.rid)
-                if not w.final:
+        with self.tracer.span("book"):
+            if n_decode:
+                self.metrics.record_decode(n_decode, dt)
+            for i, w in enumerate(works):
+                if w is None:
                     continue
-                if self.runner.autoregressive:
-                    slot.last_token = slot.req.out_tokens[-1]
+                slot = self.slots[i]
+                if i in discard:
+                    # post-completion speculative work: its token was
+                    # dropped in collect; resolve the slot the way the
+                    # earlier harvest decided
+                    if slot.eject_pending:
+                        self._eject(i)
+                    elif slot.state == DRAIN:
+                        self._finish(i)
+                    continue
+                toks = [int(x) for x in emitted[i]]
+                if isinstance(w, PrefillWork):
+                    if slot.stream is not None:
+                        self._book_emit(enabled[i])
+                    if toks:
+                        first = not slot.req.out_tokens
+                        slot.req.out_tokens.extend(toks)
+                        if first:
+                            self.metrics.record_first_token(slot.req.rid)
+                    if not w.final:
+                        continue
+                    if self.runner.autoregressive:
+                        slot.last_token = slot.req.out_tokens[-1]
+                        self._resolve_done(i)
+                    else:
+                        self._finish(i)     # slot sat in DRAIN since dispatch
+                else:
+                    token = toks[0]
+                    slot.req.out_tokens.append(token)
+                    slot.last_token = token
                     self._resolve_done(i)
-                else:
-                    self._finish(i)     # slot sat in DRAIN since dispatch
-            else:
-                token = toks[0]
-                slot.req.out_tokens.append(token)
-                slot.last_token = token
-                self._resolve_done(i)
-        # read-until verdicts surface after the tick's tokens are booked
-        pop = getattr(self.runner, "pop_ejections", None)
-        if pop is not None:
-            for i in pop():
-                s = self.slots[i]
-                if s.state == FREE or s.req is None or s.req.done:
-                    continue
-                if self._inflight is not None \
-                        and self._inflight[0][i] is not None:
-                    # a newer window is in flight: discard it at its
-                    # harvest, then eject
-                    s.eject_pending = True
-                    self._inflight[2].add(i)
-                else:
-                    self._eject(i)
+            self._book_ejections(enabled)
 
     def _resolve_done(self, i: int) -> None:
         """Completion check at harvest: finish now, or — when a newer
@@ -768,68 +815,62 @@ class ServingEngine:
 
     def _run_works(self, works: List[Optional[Any]]) -> None:
         """One runner step over the work list + all host bookkeeping:
-        emitted tokens, prefill/decode metrics, PREFILL->DECODE
-        transitions, completions."""
-        if not any(w is not None for w in works):
+        emitted tokens, decode metrics, PREFILL->DECODE transitions,
+        completions, read-until ejections."""
+        rows = sum(w is not None for w in works)
+        if not rows:
             return
+        with self._tick_span(rows):
+            self._step_sync(works)
+
+    def _step_sync(self, works: List[Optional[Any]]) -> None:
         n_decode = sum(isinstance(w, DecodeWork) for w in works)
+        enabled = self._enable_times(works)
+        self._record_window_waits(enabled)
         t0 = self.metrics.clock()
         # sync: runner.step reads the tick's emitted tokens back to the
         # host — the engine's one intentional sync point per tick
         emitted = self.runner.step(works)
         dt = self.metrics.clock() - t0
-        if n_decode:
-            self.metrics.record_decode(n_decode, dt)
-        for i, w in enumerate(works):
-            if w is None:
-                continue
-            slot = self.slots[i]
-            toks = [int(x) for x in emitted[i]]
-            if isinstance(w, PrefillWork):
-                slot.fresh = False
-                slot.pos += w.n_units
-                self.metrics.record_prefill(w.n_units)
-                if slot.stream is not None:
-                    slot.stream.consumed = slot.pos
-                    if toks:    # sample-arrival -> base-emission latency
-                        t_en = slot.req.enable_time(slot.stream.need,
-                                                    slot.stream.needs_finish)
-                        if t_en is not None:
-                            self.metrics.record_emit(
-                                max(self.metrics.clock() - t_en, 0.0))
-                if toks:
-                    first = not slot.req.out_tokens
-                    slot.req.out_tokens.extend(toks)
-                    if first:
-                        self.metrics.record_first_token(slot.req.rid)
-                if not w.final:
+        with self.tracer.span("book"):
+            if n_decode:
+                self.metrics.record_decode(n_decode, dt)
+            for i, w in enumerate(works):
+                if w is None:
                     continue
-                if self.runner.autoregressive:
-                    # prompt fully cached: the final chunk emitted the
-                    # next generated token (token #1 for fresh requests;
-                    # the resume point after a preemption)
-                    slot.last_token = slot.req.out_tokens[-1]
-                    slot.state = DECODE
-                    if slot.req.done:   # max_new_tokens reached (or EOS)
-                        self._finish(i)
+                slot = self.slots[i]
+                toks = [int(x) for x in emitted[i]]
+                if isinstance(w, PrefillWork):
+                    slot.fresh = False
+                    slot.pos += w.n_units
+                    if slot.stream is not None:
+                        slot.stream.consumed = slot.pos
+                        self._book_emit(enabled[i])
+                    if toks:
+                        first = not slot.req.out_tokens
+                        slot.req.out_tokens.extend(toks)
+                        if first:
+                            self.metrics.record_first_token(slot.req.rid)
+                    if not w.final:
+                        continue
+                    if self.runner.autoregressive:
+                        # prompt fully cached: the final chunk emitted
+                        # the next generated token (token #1 for fresh
+                        # requests; the resume point after a preemption)
+                        slot.last_token = slot.req.out_tokens[-1]
+                        slot.state = DECODE
+                        if slot.req.done:   # max_new_tokens reached (or EOS)
+                            self._finish(i)
+                    else:
+                        self._finish(i)     # reads end with their last chunk
                 else:
-                    self._finish(i)     # reads end with their last chunk
-            else:
-                slot.pos += 1           # last_token now cached at pos
-                token = toks[0]
-                slot.req.out_tokens.append(token)
-                slot.last_token = token
-                if slot.req.done:
-                    self._finish(i)
-        # read-until verdicts surface after the tick's tokens are booked
-        # (a read finishing this very tick wins over its ejection — its
-        # _finish already reset the row, clearing the pending verdict)
-        pop = getattr(self.runner, "pop_ejections", None)
-        if pop is not None:
-            for i in pop():
-                s = self.slots[i]
-                if s.state != FREE and s.req is not None and not s.req.done:
-                    self._eject(i)
+                    slot.pos += 1           # last_token now cached at pos
+                    token = toks[0]
+                    slot.req.out_tokens.append(token)
+                    slot.last_token = token
+                    if slot.req.done:
+                        self._finish(i)
+            self._book_ejections(enabled)
 
     def _ensure_decode_blocks(self) -> None:
         """Every DECODE slot writes position ``slot.pos`` this tick;
@@ -897,6 +938,9 @@ class ServingEngine:
                    if req.signal is not None else 0)
         self.metrics.record_eject(req.rid, consumed=slot.pos,
                                   arrived=arrived)
+        if slot.decided_at is not None:
+            self.tracer.record("verdict", slot.decided_at,
+                               self.metrics.clock(), rid=req.rid)
         self._complete(req)
         self.slots[i] = _Slot()
 

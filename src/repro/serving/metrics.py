@@ -12,7 +12,7 @@ preemption count.
 Bounded mode (``max_samples``): long-running serves must not grow host
 memory without bound, so the per-request table evicts the oldest DONE
 entries and the per-step sample lists become rolling windows. Aggregate
-counters (requests done, tokens generated, decode/prefill totals,
+counters (requests done, tokens generated, decode totals,
 preemptions) are kept exactly either way; only the percentile-style
 numbers (TTFT, queue depth) reduce to the rolling window.
 """
@@ -87,11 +87,8 @@ class ServingMetrics:
         self.queue_depth_hwm = 0        # exact high-water mark (the
                                         # rolling sample window may miss it)
         self.plan_stats: Dict[str, int] = {}   # runner PlanCache.stats()
-        self.decode_steps = 0
         self.decode_tokens = 0          # useful (non-pad) tokens decoded
         self.decode_time = 0.0
-        self.prefill_chunks = 0
-        self.prefill_tokens = 0
         self.start_time: Optional[float] = None
         self.end_time: Optional[float] = None
 
@@ -196,13 +193,8 @@ class ServingMetrics:
         if self._last_decode_time is not None:
             self.decode_interval_samples.append(now - self._last_decode_time)
         self._last_decode_time = now
-        self.decode_steps += 1
         self.decode_tokens += n_tokens
         self.decode_time += dt
-
-    def record_prefill(self, n_tokens: int) -> None:
-        self.prefill_chunks += 1
-        self.prefill_tokens += n_tokens
 
     # --------------------------------------------------------- summary
     def summary(self) -> Dict[str, float]:
@@ -225,9 +217,6 @@ class ServingMetrics:
             "tokens_per_s": gen / elapsed if elapsed > 0 else 0.0,
             "decode_tokens_per_s": (self.decode_tokens / self.decode_time
                                     if self.decode_time > 0 else 0.0),
-            "decode_steps": self.decode_steps,
-            "prefill_chunks": self.prefill_chunks,
-            "prefill_tokens": self.prefill_tokens,
             "preemptions": self.preempts,
             "ttft_mean_s": (sum(ttfts) / len(ttfts)) if ttfts else float("nan"),
             "ttft_p50_s": _pct(ttfts, 0.50),

@@ -77,6 +77,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig
+from repro.serving import tracing
 from repro.serving.cache import CachePool
 from repro.serving.plan import PlanCache, chunk_buckets, round_chunk
 from repro.serving.sampling import any_sampled, pack_rows, sample_tokens
@@ -773,6 +774,10 @@ class BasecallerRunner(ModelRunner):
     ejection when the mean falls below ``threshold``; the engine
     collects the flags via :meth:`pop_ejections` after booking the
     tick's bases.
+
+    Spans (``tracer``, default ``tracing.default()``): ``dispatch``
+    (``rows`` carrying a window), then in ``collect`` ``device_wait``,
+    ``readback`` and ``ctc_merge`` (``rows`` merged).
     """
 
     autoregressive = False
@@ -783,7 +788,8 @@ class BasecallerRunner(ModelRunner):
     def __init__(self, params, cfg: ModelConfig, *, n_slots: int,
                  chunk_samples: int = 1024, beam: int = 0,
                  model_state=None, qos: str = "accuracy",
-                 read_until=None, **_):
+                 read_until=None, tracer: Optional[tracing.Tracer] = None,
+                 **_):
         from repro.models.basecaller import model as bc
         from repro.models.basecaller import ctc
         self._bc, self._ctc = bc, ctc
@@ -796,6 +802,7 @@ class BasecallerRunner(ModelRunner):
         self.beam = int(beam)
         self.qos = qos
         self.read_until = read_until
+        self.tracer = tracer if tracer is not None else tracing.default()
         self.state = model_state if model_state is not None \
             else bc.init_state(cfg)
         self._merge: List[Optional[Any]] = [None] * self.n_slots
@@ -916,20 +923,23 @@ class BasecallerRunner(ModelRunner):
     def dispatch(self, works: List[Optional[Any]]) -> Any:
         """Enqueue the tick's batched window forward; log-probs (and
         classifier logits) stay on device until ``collect``."""
-        B = self.n_slots
-        W = self.core + 2 * self.halo
-        wins = np.zeros((B, W, 1), np.float32)
-        start = np.zeros((B,), np.int32)
-        read_len = np.zeros((B,), np.int32)     # 0 = idle row: all masked
-        for i, w in enumerate(works):
-            if w is None:
-                continue
-            window, _, _, st, rl, _ = w.payload
-            wins[i] = window
-            start[i] = st
-            read_len[i] = rl
-        fwd = self.plans.lookup(self._plan_key)
-        return (works, fwd(self.params, self.state, wins, start, read_len))
+        rows = sum(w is not None for w in works)
+        with self.tracer.span("dispatch", rows=rows):
+            B = self.n_slots
+            W = self.core + 2 * self.halo
+            wins = np.zeros((B, W, 1), np.float32)
+            start = np.zeros((B,), np.int32)
+            read_len = np.zeros((B,), np.int32)  # 0 = idle row: all masked
+            for i, w in enumerate(works):
+                if w is None:
+                    continue
+                window, _, _, st, rl, _ = w.payload
+                wins[i] = window
+                start[i] = st
+                read_len[i] = rl
+            fwd = self.plans.lookup(self._plan_key)
+            return (works, fwd(self.params, self.state, wins, start,
+                               read_len))
 
     def collect(self, handle: Any,
                 discard: frozenset = frozenset()) -> List[List[int]]:
@@ -938,16 +948,28 @@ class BasecallerRunner(ModelRunner):
         async engine) are dropped BEFORE the merge sees them, so an
         ejected read's bases match the synchronous engine exactly."""
         works, dev = handle
-        if self.read_until is not None:
-            lp, cls = dev
-            # sync: CTC merge (stitch/beam) and the read-until verdict
-            # are host-side by design — one readback covers both
-            lp, cls = np.asarray(lp), np.asarray(cls)
-        else:
-            # sync: CTC merge (stitch/beam) is host-side by design —
-            # every basecall tick reads the window's log-probs back
-            lp = np.asarray(dev)
-            cls = None
+        with self.tracer.span("device_wait"):
+            # sync: waits for the tick's result, which the readback
+            # below waits for anyway — split out so the two are timed
+            jax.block_until_ready(dev)
+        with self.tracer.span("readback"):
+            if self.read_until is not None:
+                lp, cls = dev
+                # sync: CTC merge (stitch/beam) and the read-until
+                # verdict are host-side by design — one readback covers
+                # both
+                lp, cls = np.asarray(lp), np.asarray(cls)
+            else:
+                # sync: CTC merge (stitch/beam) is host-side by design —
+                # every basecall tick reads the window's log-probs back
+                lp = np.asarray(dev)
+                cls = None
+        rows = sum(w is not None and i not in discard
+                   for i, w in enumerate(works))
+        with self.tracer.span("ctc_merge", rows=rows):
+            return self._merge_rows(works, lp, cls, discard)
+
+    def _merge_rows(self, works, lp, cls, discard) -> List[List[int]]:
         f0 = self.halo // self.stride
         out: List[List[int]] = []
         for i, w in enumerate(works):

@@ -476,7 +476,7 @@ def test_engine_cobatch_matches_split_tick(arch):
     split, _ = _drain(arch, "xla", spec, cache_len=48, co_batch=False)
     mixed, me = _drain(arch, "xla", spec, cache_len=48)
     assert mixed == split
-    assert me.metrics.prefill_chunks > 0
+    assert any(k[0] == "mixed" for k in me.runner.plans._warmed)
     budget, _ = _drain(arch, "xla", spec, cache_len=48,
                        max_prefill_tokens=4)
     assert budget == split

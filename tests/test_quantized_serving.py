@@ -319,7 +319,8 @@ def test_engine_quantized_backend_parity(arch, policy):
     fused, fe = _drain(arch, "pallas", spec, policy, cache_len=48)
     assert fused == ref
     assert fe.pool.quant_policy.default == policy
-    assert re.metrics.prefill_chunks > 0          # mixed ticks really ran
+    # mixed ticks really ran
+    assert any(k[0] == "mixed" for k in re.runner.plans._warmed)
 
 
 def test_engine_quantized_recycle_parity():
