@@ -209,10 +209,9 @@ def whole_read(cfg, params, state):
 def windowed(runner, signal):
     """Posteriors of one read through the runner's compiled tick, its
     windows (the runner's own chunking) filling the tick rows, core
-    frames concatenated."""
+    frames concatenated. The tick returns each row's core frames."""
     import numpy as np
     B, W = runner.n_slots, runner.core + 2 * runner.halo
-    f0 = runner.halo // runner.stride
     wins = runner._bc.chunk_windows(signal, runner.core, runner.halo,
                                     runner.stride)
     frames = []
@@ -227,7 +226,7 @@ def windowed(runner, signal):
             read_len[i] = signal.shape[0]
         lp = np.asarray(runner._fwd(runner.params, runner.state, x, start,
                                     read_len))
-        frames += [lp[i, f0:f0 + nf] for i, (_, nf, _) in enumerate(rows)]
+        frames += [lp[i, :nf] for i, (_, nf, _) in enumerate(rows)]
     return np.concatenate(frames)
 
 

@@ -265,18 +265,20 @@ def _flash_attention_jit(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def qconv1d_block(x: jax.Array, dw, pw, gamma, beta, *, relu: bool = True,
-                  interpret=None) -> jax.Array:
-    """x: (B, T, C); dw/pw: PackedTensor (int8). Fused RUBICALL block."""
+                  same: bool = True, interpret=None) -> jax.Array:
+    """x: (B, T, C); dw/pw: PackedTensor (int8). Fused RUBICALL block.
+    ``same=False``: ``x`` already carries the k-1 frames of context, and
+    the output has T - (k - 1) frames."""
     interpret = interpret_default() if interpret is None else interpret
-    return _qconv1d_block_jit(x, dw, pw, gamma, beta, relu=relu,
+    return _qconv1d_block_jit(x, dw, pw, gamma, beta, relu=relu, same=same,
                               interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("relu", "interpret"))
-def _qconv1d_block_jit(x, dw, pw, gamma, beta, *, relu, interpret):
+@functools.partial(jax.jit, static_argnames=("relu", "same", "interpret"))
+def _qconv1d_block_jit(x, dw, pw, gamma, beta, *, relu, same, interpret):
     k = dw.orig_shape[0]
     pad = (k - 1) // 2
-    xp = jnp.pad(x, ((0, 0), (pad, k - 1 - pad), (0, 0)))
+    xp = jnp.pad(x, ((0, 0), (pad, k - 1 - pad), (0, 0))) if same else x
     return qconv1d_block_p(
         xp, dw.data.reshape(k, -1), pw.data,
         jnp.asarray(dw.scale, jnp.float32).reshape(1, -1),

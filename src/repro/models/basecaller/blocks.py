@@ -13,7 +13,7 @@ TPU notes: the depthwise+pointwise pair is the Pallas ``qconv1d`` hot-spot
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +22,13 @@ from repro.config import ModelConfig
 from repro.models.lm.common import Params, truncated_normal_init
 
 State = Dict[str, jax.Array]
+
+
+class Span(NamedTuple):
+    """Frames ``[lo, hi)``, indexed as in the whole-window SAME forward,
+    so a frame keeps its index however little of the window is computed."""
+    lo: int
+    hi: int
 
 
 def conv_kernel_of(w, dtype) -> jax.Array:
@@ -46,15 +53,29 @@ def _maybe_quant(w: jax.Array, x: jax.Array, cfg: ModelConfig, tag: str):
     return w, x
 
 
+def conv_pads(k: int, dilation: int = 1, causal: bool = False
+              ) -> Tuple[int, int]:
+    """SAME padding (left, right) of a K-tap conv: one-sided if causal."""
+    total = dilation * (k - 1)
+    return (total, 0) if causal else (total // 2, total - total // 2)
+
+
+def conv_input_span(lo: int, hi: int, k: int, stride: int = 1,
+                    dilation: int = 1, causal: bool = False
+                    ) -> Tuple[int, int]:
+    """Input frames ``[lo', hi')`` that output frames ``[lo, hi)`` of the
+    SAME conv read; frames outside the input grid are its zero padding."""
+    left = conv_pads(k, dilation, causal)[0]
+    return (lo * stride - left,
+            (hi - 1) * stride - left + dilation * (k - 1) + 1)
+
+
 def conv1d(x: jax.Array, w: jax.Array, *, stride: int = 1, groups: int = 1,
-           dilation: int = 1, causal: bool = False) -> jax.Array:
-    """x: (B, S, Cin); w: (K, Cin//groups, Cout)."""
-    K = w.shape[0]
-    if causal:
-        pad = ((dilation * (K - 1), 0),)
-    else:
-        total = dilation * (K - 1)
-        pad = ((total // 2, total - total // 2),)
+           dilation: int = 1, causal: bool = False,
+           same: bool = True) -> jax.Array:
+    """x: (B, S, Cin); w: (K, Cin//groups, Cout). ``same=False``: no
+    padding — ``x`` already carries every input frame the outputs read."""
+    pad = (conv_pads(w.shape[0], dilation, causal) if same else (0, 0),)
     return jax.lax.conv_general_dilated(
         x, w, window_strides=(stride,), padding=pad,
         rhs_dilation=(dilation,), feature_group_count=groups,
@@ -109,7 +130,7 @@ def sep_conv_state(c_out: int) -> State:
 
 def sep_conv(p: Params, s: State, x: jax.Array, cfg: ModelConfig, tag: str,
              *, stride: int = 1, dilation: int = 1, causal: bool = False,
-             train: bool = True, relu: bool = True
+             train: bool = True, relu: bool = True, same: bool = True
              ) -> Tuple[jax.Array, State]:
     from repro.core.quant.policy import PackedTensor
     c_in = x.shape[-1]
@@ -129,7 +150,7 @@ def sep_conv(p: Params, s: State, x: jax.Array, cfg: ModelConfig, tag: str,
         rs = s["bn"]
         g = p["bn"]["scale"] * jax.lax.rsqrt(rs["var"] + 1e-5)
         b = p["bn"]["bias"] - rs["mean"] * g
-        h = qconv1d_block(x, dw_p, pw_p, g, b, relu=relu)
+        h = qconv1d_block(x, dw_p, pw_p, g, b, relu=relu, same=same)
         if relu and cfg.quant.enabled:
             from repro.core.quant.fake_quant import fake_quant
             _, ab = cfg.quant.bits_for(tag + "/act")
@@ -142,7 +163,7 @@ def sep_conv(p: Params, s: State, x: jax.Array, cfg: ModelConfig, tag: str,
     else:
         dw, xq = _maybe_quant(dw, x, cfg, tag + "/dw")
     h = conv1d(xq, dw, stride=stride, groups=c_in, dilation=dilation,
-               causal=causal)
+               causal=causal, same=same)
     pw = conv_kernel_of(pw_p, x.dtype)
     if isinstance(pw_p, PackedTensor):
         hq = h
@@ -183,24 +204,24 @@ def block_state(cfg: ModelConfig, i: int) -> State:
     return s
 
 
-def _mask_outside(h: jax.Array, bounds, s: int) -> jax.Array:
+def _mask_outside(h: jax.Array, bounds, s: int, lo: int = 0) -> jax.Array:
     """Zero positions outside the read (streamed-chunk serving).
 
     ``bounds = (start, read_len)`` are traced scalars — or ``(B,)``
     vectors when the serving runner batches every slot's window into
     one forward; each batch row then masks against its own read edges
     (rows with ``read_len == 0`` mask everything: inactive slots).
-    Position ``i`` at cumulative stride ``s`` anchors global sample
-    ``start + i*s``. The whole-read forward's convs implicitly zero-pad
-    beyond the read; a chunk window's halo positions beyond the read
-    edge would otherwise carry BatchNorm-biased values into the next
-    K>1 conv, breaking the chunked == whole-read bit-parity the
-    BasecallerRunner relies on.
+    Position ``i`` holds frame ``lo + i`` and, at cumulative stride
+    ``s``, anchors global sample ``start + (lo + i)*s``. The whole-read
+    forward's convs implicitly zero-pad beyond the read; a chunk
+    window's halo positions beyond the read edge would otherwise carry
+    BatchNorm-biased values into the next K>1 conv, breaking the
+    chunked == whole-read bit-parity the BasecallerRunner relies on.
     """
     if bounds is None:
         return h
     start, read_len = bounds
-    idx = jnp.arange(h.shape[1], dtype=jnp.int32) * s
+    idx = (lo + jnp.arange(h.shape[1], dtype=jnp.int32)) * s
     if jnp.ndim(start) == 1:            # per-row bounds (batched serving)
         gpos = start[:, None] + idx[None, :]
         ok = (gpos >= 0) & (gpos < read_len[:, None])
@@ -214,29 +235,48 @@ def block_forward(p: Params, s: State, x: jax.Array, cfg: ModelConfig,
                   i: int, *, train: bool = True,
                   skip_gate: Optional[jax.Array] = None,
                   dilation: int = 1, causal: bool = False,
-                  bounds=None, s_in: int = 1
+                  bounds=None, s_in: int = 1,
+                  span: Optional[Tuple[Span, ...]] = None
                   ) -> Tuple[jax.Array, State]:
+    """``span``: None computes every frame (SAME padding). Otherwise
+    ``x`` holds frames ``span[0]`` and repeat ``j`` computes only its
+    output frames ``span[j + 1]``, by unpadded convs over the frames
+    they read (:func:`conv_input_span`), which ``x`` or the repeat
+    before must hold."""
     reps = cfg.repeats[i]
     stride = cfg.strides[i]
+    k = cfg.kernel_sizes[i]
     tag = f"block{i:02d}"
     new_s: State = {}
     h = x
     for j in range(reps):
         last = (j == reps - 1)
+        st = stride if j == 0 else 1
+        s_rep = s_in if j == 0 else s_in * stride
         # each grouped (K > 1) conv must see zeros beyond the read edge,
         # exactly like the whole-read forward's implicit padding; the
         # pointwise convs / BN / ReLU in between are positionwise and
         # cannot smear out-of-read values inward, so masking the repeat
         # inputs is sufficient
-        h = _mask_outside(h, bounds, s_in if j == 0 else s_in * stride)
+        if span is None:
+            h = _mask_outside(h, bounds, s_rep)
+        else:
+            at, out = span[j], span[j + 1]
+            lo, hi = conv_input_span(out.lo, out.hi, k, st, dilation, causal)
+            h = _mask_outside(h[:, lo - at.lo:hi - at.lo], bounds, s_rep, lo)
         h, ns = sep_conv(p[f"rep{j}"], s[f"rep{j}"], h, cfg, f"{tag}/rep{j}",
-                         stride=stride if j == 0 else 1,
-                         dilation=dilation, causal=causal,
-                         train=train, relu=not last)
+                         stride=st, dilation=dilation, causal=causal,
+                         train=train, relu=not last, same=span is None)
         new_s[f"rep{j}"] = ns
     if cfg.use_skips and "skip_pw" in p:
         gate = 1.0 if skip_gate is None else skip_gate
-        sk = conv1d(x, conv_kernel_of(p["skip_pw"], x.dtype))
+        sk_in = x
+        if span is not None:
+            # the output frames' own input frames, every stride-th
+            at, out = span[0], span[-1]
+            sk_in = x[:, out.lo * stride - at.lo:
+                      (out.hi - 1) * stride + 1 - at.lo]
+        sk = conv1d(sk_in, conv_kernel_of(p["skip_pw"], x.dtype))
         if stride > 1:
             sk = sk[:, ::stride]
         sk, bn_s = batchnorm(p["skip_bn"], s["skip_bn"], sk, train=train)

@@ -37,10 +37,19 @@ def init_state(cfg: ModelConfig) -> State:
             for i in range(cfg.n_blocks)}
 
 
+def _causal(cfg: ModelConfig) -> bool:
+    return cfg.name.startswith("causalcall")
+
+
+def _dilation(cfg: ModelConfig, i: int) -> int:
+    """Block i's conv dilation: 1, 2, 4, 8, 16 cycling for causalcall."""
+    return 2 ** (i % 5) if _causal(cfg) else 1
+
+
 def forward(params: Params, state: State, signal: jax.Array,
             cfg: ModelConfig, *, train: bool = True,
             skip_gates: Optional[jax.Array] = None,
-            bounds=None) -> Tuple[jax.Array, State]:
+            bounds=None, spans=None) -> Tuple[jax.Array, State]:
     """signal: (B, S, 1) -> (log_probs (B, T, n_bases), new_state).
 
     ``skip_gates``: (n_blocks,) in [0,1] — SkipClip's anneal handle.
@@ -49,23 +58,30 @@ def forward(params: Params, state: State, signal: jax.Array,
     (may be negative at the read head) of a ``read_len``-sample read,
     and positions outside the read are re-zeroed before every K > 1
     conv so chunked outputs match the whole-read forward bit-exactly.
+    ``spans``: optional :func:`window_spans` — each block computes only
+    those frames, and T is the last block's span; None computes every
+    frame with SAME padding (T = S / stride).
     """
     x = signal.astype(cfg.dtype)
     new_state: State = {}
-    causal = cfg.name.startswith("causalcall")
+    causal = _causal(cfg)
+    at = bl.Span(0, x.shape[1])
     s_in = 1
     for i in range(cfg.n_blocks):
         gate = None if skip_gates is None else skip_gates[i]
-        dilation = 2 ** (i % 5) if causal else 1
+        span = None if spans is None else (at,) + spans[i]
         # the scope names each block's device ops in profiler traces
         with jax.named_scope(f"block{i:02d}"):
             x, ns = bl.block_forward(params[f"block{i:02d}"],
                                      state[f"block{i:02d}"], x, cfg, i,
                                      train=train, skip_gate=gate,
-                                     dilation=dilation, causal=causal,
-                                     bounds=bounds, s_in=s_in)
+                                     dilation=_dilation(cfg, i),
+                                     causal=causal, bounds=bounds,
+                                     s_in=s_in, span=span)
         new_state[f"block{i:02d}"] = ns
         s_in *= int(cfg.strides[i])
+        if spans is not None:
+            at = spans[i][-1]
     with jax.named_scope("head"):
         logits = bl.conv1d(x, bl.conv_kernel_of(params["head_pw"], x.dtype))
         log_probs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
@@ -98,10 +114,9 @@ def total_stride(cfg: ModelConfig) -> int:
 def receptive_field(cfg: ModelConfig) -> int:
     """Receptive field of one output frame, in input samples (both
     conv dilation — causalcall — and strides accounted)."""
-    causal = cfg.name.startswith("causalcall")
     r, s = 1, 1
     for i in range(cfg.n_blocks):
-        dil = 2 ** (i % 5) if causal else 1
+        dil = _dilation(cfg, i)
         for j in range(cfg.repeats[i]):
             r += (cfg.kernel_sizes[i] - 1) * dil * s
             if j == 0:
@@ -142,11 +157,49 @@ def chunk_windows(signal: np.ndarray, core: int, halo: int, stride: int
     return out
 
 
+def window_spans(cfg: ModelConfig, n_samples: int
+                 ) -> Tuple[Tuple[bl.Span, ...], ...]:
+    """Per block, per repeat: the output frames a window of
+    ``n_samples = core + 2 * chunk_halo(cfg)`` samples needs for its core,
+    on the whole-window SAME forward's frame grid. The last block's span
+    is the core's frames; walking back, each conv needs the frames its
+    outputs read (:func:`blocks.conv_input_span`). The halo covers the
+    receptive field, so no span reaches the SAME padding: every frame
+    computed is a frame the whole-window forward computes."""
+    halo, st = chunk_halo(cfg), total_stride(cfg)
+    if n_samples <= 2 * halo or n_samples % st:
+        raise ValueError(f"a window of {n_samples} samples has no core of "
+                         f"whole frames inside its {halo}-sample halos")
+    spans: List[List[bl.Span]] = [[] for _ in range(cfg.n_blocks)]
+    lo, hi = halo // st, (n_samples - halo) // st
+    for i in reversed(range(cfg.n_blocks)):
+        for j in reversed(range(cfg.repeats[i])):
+            spans[i].insert(0, bl.Span(lo, hi))
+            lo, hi = bl.conv_input_span(
+                lo, hi, cfg.kernel_sizes[i],
+                int(cfg.strides[i]) if j == 0 else 1, _dilation(cfg, i),
+                _causal(cfg))
+    if lo < 0 or hi > n_samples:
+        raise ValueError(f"block00 would read samples [{lo}, {hi}) outside "
+                         f"the {n_samples}-sample window")
+    return tuple(tuple(b) for b in spans)
+
+
+def span_frames(spans) -> int:
+    """Frames one row of the window forward computes: every conv's
+    output span (for one-repeat blocks, one span a block)."""
+    return sum(sp.hi - sp.lo for block in spans for sp in block)
+
+
 def forward_window(params: Params, state: State, window: jax.Array,
                    cfg: ModelConfig, start: jax.Array, read_len: jax.Array
                    ) -> jax.Array:
     """Eval-mode forward over one padded window (B, W, 1) -> CTC
-    log-probs (B, W/stride, n_bases). ``start``/``read_len`` are traced
+    log-probs of its core frames (B, core/stride, n_bases), core =
+    W - 2 * chunk_halo(cfg). Each block computes only the frames the
+    core still needs (:func:`window_spans`), so a core frame sees the
+    same samples, weights and read-edge masks as in the whole-window
+    SAME forward. ``start``/``read_len`` are traced
     scalars — or ``(B,)`` vectors when the serving runner co-batches
     every slot's window into one forward, each row masking against its
     own read edges (global sample of window[0] — negative at the read
@@ -154,7 +207,8 @@ def forward_window(params: Params, state: State, window: jax.Array,
     retraces nothing. The jitted hot loop of the serving
     BasecallerRunner (one compile — all windows share W)."""
     log_probs, _ = forward(params, state, window, cfg, train=False,
-                           bounds=(start, read_len))
+                           bounds=(start, read_len),
+                           spans=window_spans(cfg, window.shape[1]))
     return log_probs
 
 

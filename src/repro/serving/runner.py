@@ -776,7 +776,9 @@ class BasecallerRunner(ModelRunner):
     tick's bases.
 
     Spans (``tracer``, default ``tracing.default()``): ``dispatch``
-    (``rows`` carrying a window), then in ``collect`` ``device_wait``,
+    (``rows`` carrying a window; ``frames``, the frame-rows the forward
+    computes for those rows, summed over its convs' spans —
+    ``model.window_spans``), then in ``collect`` ``device_wait``,
     ``readback`` and ``ctc_merge`` (``rows`` merged).
     """
 
@@ -823,7 +825,9 @@ class BasecallerRunner(ModelRunner):
                 return bc.forward_window(p, s, w, cfg, start, read_len)
         # one window geometry -> one plan; warmup pre-pays the compile
         # and the plan cache's retrace counter covers streaming ticks
-        self._plan_key = ("window", self.core + 2 * self.halo, "fwd")
+        W = self.core + 2 * self.halo
+        self._row_frames = bc.span_frames(bc.window_spans(cfg, W))
+        self._plan_key = ("window", W, "fwd")
         self.plans = PlanCache()
         self.plans.register(self._plan_key, fwd)
 
@@ -924,7 +928,8 @@ class BasecallerRunner(ModelRunner):
         """Enqueue the tick's batched window forward; log-probs (and
         classifier logits) stay on device until ``collect``."""
         rows = sum(w is not None for w in works)
-        with self.tracer.span("dispatch", rows=rows):
+        with self.tracer.span("dispatch", rows=rows,
+                              frames=rows * self._row_frames):
             B = self.n_slots
             W = self.core + 2 * self.halo
             wins = np.zeros((B, W, 1), np.float32)
@@ -970,14 +975,13 @@ class BasecallerRunner(ModelRunner):
             return self._merge_rows(works, lp, cls, discard)
 
     def _merge_rows(self, works, lp, cls, discard) -> List[List[int]]:
-        f0 = self.halo // self.stride
         out: List[List[int]] = []
         for i, w in enumerate(works):
             if w is None or i in discard:
                 out.append([])
                 continue
             _, f_lo, f_hi, _, _, classify = w.payload
-            core = lp[i, f0 + f_lo:f0 + f_hi]
+            core = lp[i, f_lo:f_hi]
             merge = self._merge[i]
             toks = merge.feed(core if self.beam
                               else np.argmax(core, axis=-1))

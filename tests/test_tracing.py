@@ -171,7 +171,8 @@ def test_engine_records_window_wait_and_verdict_spans(smoke, async_dispatch):
     assert {"serving.dispatch", "serving.device_wait", "serving.readback",
             "serving.ctc_merge", "serving.book"} <= set(one)
     assert one["serving.dispatch"].parent == ticks[0].index
-    assert one["serving.dispatch"].attrs == {"rows": 2}
+    frames = bc.span_frames(bc.window_spans(cfg, CHUNK + 2 * halo)) * 2
+    assert one["serving.dispatch"].attrs == {"rows": 2, "frames": frames}
     assert one["serving.ctc_merge"].attrs == {"rows": 2}
     if not async_dispatch:
         for name in ("serving.device_wait", "serving.readback",
@@ -196,7 +197,7 @@ def test_engine_records_window_wait_and_verdict_spans(smoke, async_dispatch):
 def test_forward_names_each_block_and_the_head_in_hlo():
     cfg = get_config("rubicall-smoke")
     params = api.init_params(jax.random.key(0), cfg)
-    W = 4 * bc.total_stride(cfg) * 8
+    W = 4 * bc.total_stride(cfg) * 8 + 2 * bc.chunk_halo(cfg)
     fwd = jax.jit(lambda p, s, w, a, n: bc.forward_window(p, s, w, cfg, a, n))
     text = fwd.lower(params, bc.init_state(cfg),
                      np.zeros((2, W, 1), np.float32),
