@@ -2,30 +2,45 @@
 
 Independent of the program: it reads the architecture from the cell's
 configuration file (``bench/configs/<name>.json``) and imports nothing
-from ``repro``. It holds
+from ``repro``. The file's ``arch`` names its block structure, a module
+``bench/arch/<arch>.py`` that provides
+
+- ``raw_weights(key, cfg)`` and ``unit_state(cfg)``: the weights at
+  unit gain in float32 and BatchNorm statistics of mean 0 and variance
+  1 (a dict holding ``mean`` and ``var``), in the pytree layout the
+  program serves; the CTC head's weight is ``params["head_pw"]``,
+  shaped (1, C, n_bases);
+- ``SERVED``: the names of the weight leaves served in the compute
+  dtype;
+- ``forward(params, state, signal, read_len, cfg, *, precision,
+  calibrate)``: the whole-read float32 forward of (B, S, 1) signal
+  with every conv operand rounded to ``precision`` (the control; see
+  ``_round_to``) and, with ``calibrate``, BatchNorm statistics taken
+  from its inputs; returns the log-probs, the state and the head's
+  input features;
+- ``total_stride(cfg)`` and ``receptive_field(cfg)``, in samples;
+- ``flops_per_frame(cfg)`` and ``weight_bytes(cfg)``, for
+  ``bench/flops.py``;
+- ``program_keys(cfg)``: the fields of the program's registered
+  configuration, with the values this file gives them, that
+  ``bench/run.py`` compares.
+
+This module holds what no block structure changes:
 
 - the weight maker: every weight and BatchNorm statistic of a cell,
-  made on the device from the seed in one jitted call, in the pytree
-  layout the program serves (conv weights bfloat16, BatchNorm float32);
-- the whole-read forward, float32 at full matmul precision, with each
-  conv input and weight optionally rounded to a lower precision (the
-  control);
+  made on the device from the seed in one jitted call (the ``SERVED``
+  leaves in bfloat16, the rest float32) and calibrated on simulated
+  reads;
+- the weight bit-widths and the rounding of a conv operand;
+- ``Reference``, the compiled whole-read forward;
 - the read-until classifier head;
 - the banded CTC Viterbi alignment that scores served bases against
   reference log-probs.
-
-The forward follows the published block: R repeats of depthwise conv ->
-pointwise conv -> BatchNorm (eval) -> ReLU (none after the last repeat),
-an optional pointwise skip with its own BatchNorm added before the
-block's ReLU, weights rounded to the configured per-layer bit-widths on
-a symmetric per-output-channel grid, and a pointwise CTC head with a
-log-softmax. A read is zero outside its samples: positions at or past
-its length are zeroed before every repeat, as an unpadded read's convs
-would see them.
 """
 from __future__ import annotations
 
 import functools
+import importlib.util
 import json
 from pathlib import Path
 from typing import Dict, Optional, Tuple
@@ -34,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+ARCHS = Path(__file__).resolve().parent / "arch"
 HIGHEST = jax.lax.Precision.HIGHEST
 BLANK = 0
 # logit scale of the random CTC head: head weights of std
@@ -53,8 +69,27 @@ VAR_FLOOR = 0.2
 
 def load_config(path) -> Dict:
     cfg = json.loads(Path(path).read_text())
-    cfg["n_blocks"] = len(cfg["channels"])
+    if "arch" not in cfg:
+        raise ValueError(f"{path} names no 'arch': each configuration "
+                         f"names its block structure, a module in {ARCHS}")
+    arch(cfg)
     return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def _load_arch(path: Path):
+    if not path.is_file():
+        raise ValueError(f"no block structure module {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_arch_{path.stem}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def arch(cfg: Dict):
+    """The module of ``cfg``'s block structure, ``ARCHS/<arch>.py``."""
+    return _load_arch(ARCHS / f"{cfg['arch']}.py")
 
 
 def weight_bits(cfg: Dict, tag: str) -> int:
@@ -69,17 +104,11 @@ def weight_bits(cfg: Dict, tag: str) -> int:
 
 
 def total_stride(cfg: Dict) -> int:
-    return int(np.prod(cfg["strides"]))
+    return arch(cfg).total_stride(cfg)
 
 
 def receptive_field(cfg: Dict) -> int:
-    r, s = 1, 1
-    for i in range(cfg["n_blocks"]):
-        for j in range(cfg["repeats"][i]):
-            r += (cfg["kernel_sizes"][i] - 1) * s
-            if j == 0:
-                s *= int(cfg["strides"][i])
-    return r
+    return arch(cfg).receptive_field(cfg)
 
 
 # --------------------------------------------------------------- weights
@@ -87,47 +116,6 @@ def receptive_field(cfg: Dict) -> int:
 
 def _normal(key, shape, std):
     return std * jax.random.normal(key, shape, jnp.float32)
-
-
-def _raw_weights(key, cfg: Dict):
-    params = {}
-    keys = jax.random.split(key, cfg["n_blocks"] + 1)
-    c_in = 1
-    for i in range(cfg["n_blocks"]):
-        c, k, reps = (cfg["channels"][i], cfg["kernel_sizes"][i],
-                      cfg["repeats"][i])
-        bk = jax.random.split(keys[i], 2 * reps + 1)
-        blk = {}
-        for j in range(reps):
-            ci = c_in if j == 0 else c
-            blk[f"rep{j}"] = {
-                "dw": _normal(bk[2 * j], (k, 1, ci), 1.0 / np.sqrt(k)),
-                "pw": _normal(bk[2 * j + 1], (1, ci, c), np.sqrt(2.0 / ci)),
-                "bn": {"scale": jnp.ones((c,), jnp.float32),
-                       "bias": jnp.zeros((c,), jnp.float32)}}
-        if cfg["use_skips"]:
-            blk["skip_pw"] = _normal(bk[-1], (1, c_in, c),
-                                     np.sqrt(1.0 / c_in))
-            blk["skip_bn"] = {"scale": jnp.ones((c,), jnp.float32),
-                              "bias": jnp.zeros((c,), jnp.float32)}
-        params[f"block{i:02d}"] = blk
-        c_in = c
-    params["head_pw"] = _normal(keys[-1], (1, c_in, cfg["n_bases"]),
-                                HEAD_GAIN / np.sqrt(c_in))
-    return params
-
-
-def _unit_state(cfg: Dict):
-    state = {}
-    for i in range(cfg["n_blocks"]):
-        c = cfg["channels"][i]
-        bn = {"mean": jnp.zeros((c,), jnp.float32),
-              "var": jnp.ones((c,), jnp.float32)}
-        s = {f"rep{j}": {"bn": dict(bn)} for j in range(cfg["repeats"][i])}
-        if cfg["use_skips"]:
-            s["skip_bn"] = dict(bn)
-        state[f"block{i:02d}"] = s
-    return state
 
 
 def make_weights(key, cfg: Dict, calib: jax.Array):
@@ -140,26 +128,33 @@ def make_weights(key, cfg: Dict, calib: jax.Array):
     weights, as a trained model's running statistics keep activations at
     unit scale, and then raised by ``VAR_FLOOR``. Call under
     ``jax.jit`` with ``cfg`` static."""
-    conv = ("dw", "pw", "skip_pw", "head_pw")
+    mod = arch(cfg)
     params = jax.tree_util.tree_map_with_path(
         lambda path, w: w.astype(jnp.bfloat16)
-        if path[-1].key in conv else w, _raw_weights(key, cfg))
+        if path[-1].key in mod.SERVED else w, mod.raw_weights(key, cfg))
     n = jnp.full((calib.shape[0],), calib.shape[1], jnp.int32)
-    _, state, _ = _forward(params, _unit_state(cfg), calib, n, cfg,
-                           calibrate=True)
-    for blk in state.values():
-        for bn in blk.values():
-            bn = bn.get("bn", bn)
-            bn["var"] = bn["var"] + VAR_FLOOR * jnp.mean(bn["var"])
+    _, state, _ = mod.forward(params, mod.unit_state(cfg), calib, n, cfg,
+                              calibrate=True)
+    _floor_var(state)
     # no label may win by a bias: each head column is made orthogonal to
     # the mean served feature, so every label's mean logit is 0 on every
     # seed
-    _, _, feats = _forward(params, state, calib, n, cfg)
+    _, _, feats = mod.forward(params, state, calib, n, cfg)
     mu = jnp.mean(feats, axis=(0, 1))
     w = params["head_pw"][0]
     w = w - jnp.outer(mu, mu @ w) / jnp.dot(mu, mu)
     params["head_pw"] = w[None].astype(jnp.bfloat16)
     return params, state
+
+
+def _floor_var(state):
+    """Raise every BatchNorm variance in ``state`` by ``VAR_FLOOR`` of
+    its layer's mean variance, in place."""
+    if "var" in state:
+        state["var"] = state["var"] + VAR_FLOOR * jnp.mean(state["var"])
+        return
+    for s in state.values():
+        _floor_var(s)
 
 
 # --------------------------------------------------------------- forward
@@ -188,64 +183,6 @@ def _quant_weight(w, bits: int):
     return jnp.clip(jnp.round(w / s), -qmax - 1, qmax) * s
 
 
-def _conv(x, w, *, stride: int = 1, groups: int = 1,
-          precision: Optional[str] = None):
-    total = w.shape[0] - 1
-    return jax.lax.conv_general_dilated(
-        _round_to(x, precision), _round_to(w, precision),
-        window_strides=(stride,), padding=((total // 2, total - total // 2),),
-        feature_group_count=groups, dimension_numbers=("NWC", "WIO", "NWC"),
-        precision=HIGHEST)
-
-
-def _bn(p, s, x, calibrate: bool):
-    if calibrate:
-        s = {"mean": jnp.mean(x, axis=(0, 1)), "var": jnp.var(x, axis=(0, 1))}
-    y = (x - s["mean"]) * jax.lax.rsqrt(s["var"] + 1e-5) * p["scale"] \
-        + p["bias"]
-    return y, s
-
-
-def _forward(params, state, signal, read_len, cfg: Dict, *,
-             precision: Optional[str] = None, calibrate: bool = False):
-    x = signal.astype(jnp.float32)
-    new_state = {}
-    s_in = 1
-    for i in range(cfg["n_blocks"]):
-        tag = f"block{i:02d}"
-        p, st = params[tag], state[tag]
-        stride = int(cfg["strides"][i])
-        reps = cfg["repeats"][i]
-        ns = {}
-        h = x
-        for j in range(reps):
-            s = s_in if j == 0 else s_in * stride
-            pos = jnp.arange(h.shape[1], dtype=jnp.int32) * s
-            h = h * (pos[None, :] < read_len[:, None])[..., None]
-            rp = p[f"rep{j}"]
-            dw = _quant_weight(rp["dw"], weight_bits(cfg, f"{tag}/rep{j}/dw"))
-            pw = _quant_weight(rp["pw"], weight_bits(cfg, f"{tag}/rep{j}/pw"))
-            h = _conv(h, dw, stride=stride if j == 0 else 1,
-                      groups=h.shape[-1], precision=precision)
-            h = _conv(h, pw, precision=precision)
-            h, bs = _bn(rp["bn"], st[f"rep{j}"]["bn"], h, calibrate)
-            ns[f"rep{j}"] = {"bn": bs}
-            if j < reps - 1:
-                h = jax.nn.relu(h)
-        if cfg["use_skips"]:
-            sk = _conv(x, p["skip_pw"].astype(jnp.float32),
-                       precision=precision)[:, ::stride]
-            sk, bs = _bn(p["skip_bn"], st["skip_bn"], sk, calibrate)
-            ns["skip_bn"] = bs
-            h = h + sk
-        x = jax.nn.relu(h)
-        new_state[tag] = ns
-        s_in *= stride
-    logits = _conv(x, params["head_pw"].astype(jnp.float32),
-                   precision=precision)
-    return jax.nn.log_softmax(logits, axis=-1), new_state, x
-
-
 class Reference:
     """Whole-read forward of one configuration, one compiled program per
     padded length (powers of two from 2**16 samples)."""
@@ -256,9 +193,10 @@ class Reference:
         self.cfg = cfg
         self.params, self.state = params, state
         self.stride = total_stride(cfg)
+        forward = arch(cfg).forward
         self._fwd = jax.jit(
-            lambda p, s, x, n: _forward(p, s, x, n, cfg,
-                                        precision=precision)[0])
+            lambda p, s, x, n: forward(p, s, x, n, cfg,
+                                       precision=precision)[0])
         self._last: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
 
     def log_probs(self, signal: np.ndarray) -> np.ndarray:
@@ -277,7 +215,9 @@ class Reference:
         x[0, :n, 0] = signal
         lp = self._fwd(self.params, self.state, x,
                        np.asarray([n], np.int32))
-        return np.asarray(lp[0, :-(-n // self.stride)])
+        # cut on the host: a slice on the device would compile one
+        # program per read length, and fill the persistent compile cache
+        return np.asarray(lp)[0, :-(-n // self.stride)]
 
 
 # ----------------------------------------------------- read-until head

@@ -4,7 +4,8 @@
         --trace 0
 
 The cell's entry in ``BENCHMARK.json`` names its configuration
-(``bench/configs/<config>.json``) and traffic mix
+(``bench/configs/<config>.json``, whose ``arch`` names its block
+structure ``bench/arch/<arch>.py``) and traffic mix
 (``bench/traffic/<traffic>.json``, whose ``kind`` names the generator
 module in ``bench/traffic/``); its per-layer metrics are readers in
 ``bench/metrics/<name>.py``. The run makes the weights and the traffic
@@ -61,9 +62,11 @@ def cell_metrics(entries, workload: str):
 
 def program_config(rc):
     """The program's registered configuration, run as ``rc`` states it:
-    activations in the compute dtype, the file's weight bit-widths. Any
-    other difference from the file is an error."""
+    activations in the compute dtype, the file's weight bit-widths. A
+    field that the block structure's ``program_keys`` gives another
+    value is an error."""
     import dataclasses
+    from bench import reference
     from repro.config import get_config
     cfg = get_config(rc["registry"])
     q = rc["quant"]
@@ -71,12 +74,7 @@ def program_config(rc):
         cfg.quant, weight_bits=q["weight_bits"], act_bits=q["act_bits"],
         overrides=tuple((p, (w, q["act_bits"])) for p, w in q["overrides"]))
     cfg = dataclasses.replace(cfg, quant=quant, dtype=rc["dtype"])
-    want = {"channels": tuple(rc["channels"]),
-            "kernel_sizes": tuple(rc["kernel_sizes"]),
-            "strides": tuple(rc["strides"]), "repeats": tuple(rc["repeats"]),
-            "use_skips": rc["use_skips"], "n_bases": rc["n_bases"],
-            "n_blocks": rc["n_blocks"]}
-    for k, v in want.items():
+    for k, v in reference.arch(rc).program_keys(rc).items():
         if getattr(cfg, k) != v:
             raise SystemExit(f"program config {rc['registry']!r} has {k}="
                              f"{getattr(cfg, k)!r}, the cell's file {v!r}")

@@ -114,7 +114,7 @@ def test_control_fails_where_sound_bf16_holds(monkeypatch):
     from bench import reference
 
     rc = reference.load_config(bench_run.ROOT / "bench/configs/rubicall.json")
-    narrow = dict(rc, channels=[32] * rc["n_blocks"])
+    narrow = dict(rc, channels=[32] * len(rc["channels"]))
     monkeypatch.setattr(reference, "load_config",
                         lambda path: dict(narrow))
     program_config = bench_run.program_config

@@ -21,8 +21,9 @@ GEOMETRY = {"core": 300, "halo": 297, "stride": 3}
      + 4 * 2 * (33 * 464 + 464 * 464) + 2 * 344 * 464),
 ])
 def test_flops_one_block_by_hand(c_in, c, k, reps, skips, want):
-    cfg = {"channels": [c_in, c], "kernel_sizes": [1, k], "strides": [1, 1],
-           "repeats": [1, reps], "use_skips": skips, "n_bases": 0}
+    cfg = {"arch": "sepconv", "channels": [c_in, c], "kernel_sizes": [1, k],
+           "strides": [1, 1], "repeats": [1, reps], "use_skips": skips,
+           "n_bases": 0}
     stem = flops.flops_per_frame(dict(cfg, channels=[c_in],
                                       kernel_sizes=[1], repeats=[1]))
     assert flops.flops_per_frame(cfg) - stem == want
@@ -42,13 +43,34 @@ def test_flops_whole_model(path, mflop):
 
 
 def test_tick_work_and_least_time():
-    cfg = {"channels": [4], "kernel_sizes": [3], "strides": [1],
-           "repeats": [1], "use_skips": False, "n_bases": 5,
+    cfg = {"arch": "sepconv", "channels": [4], "kernel_sizes": [3],
+           "strides": [1], "repeats": [1], "use_skips": False, "n_bases": 5,
            "dtype": "bfloat16"}
     w = flops.tick_work(cfg, frames=10, samples=10)
     assert w["flops"] == 10 * (2 * (3 * 1 + 1 * 4) + 2 * 4 * 5)
     assert w["bytes"] == (3 + 4 + 20) * 2 + 4 * 4 * 4 + 40 + 200
     assert flops.least_time_s(w, 1.0, 1e9) == w["flops"]
+
+
+def test_reference_compiles_once_per_padded_length(caplog):
+    """Reads of different lengths in one padding bucket share one
+    program: nothing compiled per read enters the compile cache."""
+    import logging
+    import jax
+    from bench import reference
+    cfg = reference.load_config(DATA / "rubicall_smoke.json")
+    params, state = jax.eval_shape(
+        lambda k, c: reference.make_weights(k, cfg, c), jax.random.key(0),
+        jax.ShapeDtypeStruct((1, 4096, 1), np.float32))
+    params, state = jax.tree_util.tree_map(
+        lambda a: np.ones(a.shape, a.dtype), (params, state))
+    ref = reference.Reference(cfg, params, state)
+    ref.log_probs(np.ones(3000, np.float32))
+    with jax.log_compiles(), caplog.at_level(logging.WARNING):
+        caplog.clear()
+        lp = ref.log_probs(np.ones(4001, np.float32))
+    assert lp.shape == (1334, 5)
+    assert not [r for r in caplog.records if "ompil" in r.getMessage()]
 
 
 def test_peaks_lookup_and_unknown_device():
