@@ -97,6 +97,11 @@ class ModelConfig:
     strides: Tuple[int, ...] = ()
     repeats: Tuple[int, ...] = ()
     use_skips: bool = False
+    # per block; () -> every block separable / a residual where use_skips
+    separable: Tuple[bool, ...] = ()
+    residual: Tuple[bool, ...] = ()
+    activation: str = "relu"      # "relu" | "swish"
+    head_bias: bool = False       # CTC head's 1x1 conv carries a bias
     n_bases: int = 5              # A C G T + CTC blank
     # ---- numerics / training ----
     dtype: str = "bfloat16"
@@ -127,6 +132,14 @@ class ModelConfig:
     @property
     def has_decoder(self) -> bool:
         return True  # every assigned arch has an autoregressive decoder
+
+    def block_separable(self, i: int) -> bool:
+        """Block i's convs: depthwise + pointwise, or one full conv."""
+        return self.separable[i] if self.separable else True
+
+    def block_residual(self, i: int) -> bool:
+        """Block i adds a 1x1 conv + BatchNorm of its input."""
+        return self.residual[i] if self.residual else self.use_skips
 
     def param_count(self) -> int:
         """Analytic parameter count (used by benchmarks & latency model)."""
@@ -176,6 +189,8 @@ class ModelConfig:
             kw["kernel_sizes"] = self.kernel_sizes[:4]
             kw["strides"] = self.strides[:4]
             kw["repeats"] = tuple(min(r, 1) for r in self.repeats[:4])
+            kw["separable"] = self.separable[:4]
+            kw["residual"] = self.residual[:4]
         return replace(self, **kw)
 
 
@@ -229,7 +244,7 @@ ASSIGNED_ARCHS = (
     "whisper-tiny",
 )
 
-PAPER_ARCHS = ("rubicall", "bonito", "causalcall")
+PAPER_ARCHS = ("rubicall", "bonito", "causalcall", "bonito-r9.4.1")
 
 
 def register(cfg: ModelConfig) -> ModelConfig:

@@ -1,8 +1,11 @@
-"""Bonito-style baseline — QuartzNet-like CTC CNN WITH skip connections.
+"""Bonito-style SkipClip teacher — QuartzNet-like CTC CNN WITH skip
+connections, at the program's own widths (not a released config's).
 
-This is the paper's most-accurate baseline and the SkipClip teacher.
 Block = R repeats of (grouped conv + pointwise conv + BN + ReLU) with a
 residual skip (pointwise-projected) around the repeats. FP32 weights.
+Bonito's released R9.4.1 model, at its published widths (full stem,
+per-block residuals, swish, head bias), is the ``bonito-r9.4.1`` entry
+(:mod:`repro.configs.bonito_r9_4_1`).
 """
 from repro.config import ModelConfig, register
 

@@ -1,11 +1,18 @@
 """Quantized 1-D conv blocks — the RUBICALL/Bonito building material.
 
-Block = R repeats of [grouped (depthwise) conv -> pointwise conv -> BN ->
-quantized ReLU], with an optional skip branch (pointwise projection of the
-block input, added before the last activation — QuartzNet/Bonito style).
+Block = R repeats of a conv -> BN -> activation, with no activation
+after the last repeat, then an optional residual branch (a pointwise
+projection of the block input + its own BN, added before the block's
+activation — QuartzNet/Bonito style), then the activation. A repeat's
+conv is separable (grouped depthwise conv -> pointwise conv) or, where
+``cfg.block_separable(i)`` is false, one full ``(K, C_in, C_out)`` conv;
+``cfg.block_residual(i)`` says which blocks carry the residual. The
+activation is ReLU or swish (``cfg.activation``, ReLU fake-quantized
+by the policy's activation bits) and every BN, applied or folded, uses
+``cfg.norm_eps``.
 
-Skip branches are gated by a per-block ``skip_gate`` in [0, 1] so SkipClip
-can anneal them away without retracing; a gate of exactly 0 is
+Residual branches are gated by a per-block ``skip_gate`` in [0, 1] so
+SkipClip can anneal them away without retracing; a gate of exactly 0 is
 algebraically identical to the skip-free (RUBICALL) topology.
 
 TPU notes: the depthwise+pointwise pair is the Pallas ``qconv1d`` hot-spot
@@ -92,7 +99,7 @@ def make_bn_state(c: int) -> State:
             "var": jnp.ones((c,), jnp.float32)}
 
 
-def batchnorm(p: Params, s: State, x: jax.Array, *, train: bool,
+def batchnorm(p: Params, s: State, x: jax.Array, *, train: bool, eps: float,
               momentum: float = 0.9) -> Tuple[jax.Array, State]:
     xf = x.astype(jnp.float32)
     if train:
@@ -103,7 +110,7 @@ def batchnorm(p: Params, s: State, x: jax.Array, *, train: bool,
     else:
         mean, var = s["mean"], s["var"]
         new_s = s
-    y = (xf - mean) * jax.lax.rsqrt(var + 1e-5) * p["scale"] + p["bias"]
+    y = (xf - mean) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
     return y.astype(x.dtype), new_s
 
 
@@ -116,27 +123,57 @@ def make_sep_conv_params(rng, c_in: int, c_out: int, k: int) -> Params:
     }
 
 
-def make_conv_params(rng, k: int, c_in: int, c_out: int) -> jax.Array:
-    """Plain (non-separable) ``(K, Cin, Cout)`` conv kernel — the
-    basecaller blocks themselves are depthwise-separable (see
-    :func:`make_sep_conv_params`); the read-until classifier head uses
-    full convs because its channel counts are tiny."""
-    return truncated_normal_init(rng, (k, c_in, c_out), stddev=0.2)
+def make_conv_params(rng, k: int, c_in: int, c_out: int,
+                     stddev: float = 0.2) -> jax.Array:
+    """Plain (non-separable) ``(K, Cin, Cout)`` conv kernel: the read-until
+    classifier head's convs, and a block's full convs
+    (:func:`make_full_conv_params`)."""
+    return truncated_normal_init(rng, (k, c_in, c_out), stddev=stddev)
+
+
+def make_full_conv_params(rng, c_in: int, c_out: int, k: int) -> Params:
+    return {"conv": make_conv_params(rng, k, c_in, c_out,
+                                     stddev=(k * c_in) ** -0.5),
+            "bn": make_bn_params(c_out)}
 
 
 def sep_conv_state(c_out: int) -> State:
     return {"bn": make_bn_state(c_out)}
 
 
+ACTIVATIONS = {"relu": jax.nn.relu, "swish": jax.nn.swish}
+
+
+def _bn_act(p: Params, s: State, h: jax.Array, cfg: ModelConfig, tag: str,
+            *, train: bool, act: bool) -> Tuple[jax.Array, State]:
+    """A conv's BatchNorm, then (``act``) the activation with the
+    policy's activation fake-quant."""
+    h, bn_s = batchnorm(p["bn"], s["bn"], h, train=train, eps=cfg.norm_eps)
+    if act:
+        h = activate(h, cfg, tag)
+    return h, {"bn": bn_s}
+
+
+def activate(h: jax.Array, cfg: ModelConfig, tag: str) -> jax.Array:
+    h = ACTIVATIONS[cfg.activation](h)
+    if cfg.quant.enabled:
+        from repro.core.quant.fake_quant import fake_quant
+        _, ab = cfg.quant.bits_for(tag + "/act")
+        if ab:
+            h = fake_quant(h, ab)
+    return h
+
+
 def sep_conv(p: Params, s: State, x: jax.Array, cfg: ModelConfig, tag: str,
              *, stride: int = 1, dilation: int = 1, causal: bool = False,
-             train: bool = True, relu: bool = True, same: bool = True
+             train: bool = True, act: bool = True, same: bool = True
              ) -> Tuple[jax.Array, State]:
     from repro.core.quant.policy import PackedTensor
     c_in = x.shape[-1]
     dw_p, pw_p = p["dw"], p["pw"]
     if (isinstance(dw_p, PackedTensor) and isinstance(pw_p, PackedTensor)
             and not train and stride == 1 and dilation == 1 and not causal
+            and cfg.activation == "relu"
             and dw_p.bits == 8 and pw_p.bits == 8
             and pw_p.orig_shape[-2] == pw_p.orig_shape[-1]
             and cfg.quant.bits_for(tag + "/pw")[0] in (4, 8)):
@@ -148,10 +185,10 @@ def sep_conv(p: Params, s: State, x: jax.Array, cfg: ModelConfig, tag: str,
         # through unchanged.
         from repro.kernels.ops import qconv1d_block
         rs = s["bn"]
-        g = p["bn"]["scale"] * jax.lax.rsqrt(rs["var"] + 1e-5)
+        g = p["bn"]["scale"] * jax.lax.rsqrt(rs["var"] + cfg.norm_eps)
         b = p["bn"]["bias"] - rs["mean"] * g
-        h = qconv1d_block(x, dw_p, pw_p, g, b, relu=relu, same=same)
-        if relu and cfg.quant.enabled:
+        h = qconv1d_block(x, dw_p, pw_p, g, b, relu=act, same=same)
+        if act and cfg.quant.enabled:
             from repro.core.quant.fake_quant import fake_quant
             _, ab = cfg.quant.bits_for(tag + "/act")
             if ab:
@@ -170,15 +207,18 @@ def sep_conv(p: Params, s: State, x: jax.Array, cfg: ModelConfig, tag: str,
     else:
         pw, hq = _maybe_quant(pw, h, cfg, tag + "/pw")
     h = conv1d(hq, pw)
-    h, bn_s = batchnorm(p["bn"], s["bn"], h, train=train)
-    if relu:
-        h = jax.nn.relu(h)
-        if cfg.quant.enabled:
-            from repro.core.quant.fake_quant import fake_quant
-            _, ab = cfg.quant.bits_for(tag + "/act")
-            if ab:
-                h = fake_quant(h, ab)
-    return h, {"bn": bn_s}
+    return _bn_act(p, s, h, cfg, tag, train=train, act=act)
+
+
+def full_conv(p: Params, s: State, x: jax.Array, cfg: ModelConfig, tag: str,
+              *, stride: int = 1, dilation: int = 1, causal: bool = False,
+              train: bool = True, act: bool = True, same: bool = True
+              ) -> Tuple[jax.Array, State]:
+    """One full ``(K, C_in, C_out)`` conv -> BN -> activation (``act``)."""
+    w, xq = _maybe_quant(p["conv"].astype(x.dtype), x, cfg, tag + "/conv")
+    h = conv1d(xq, w, stride=stride, dilation=dilation, causal=causal,
+               same=same)
+    return _bn_act(p, s, h, cfg, tag, train=train, act=act)
 
 
 def make_block_params(rng, cfg: ModelConfig, i: int, c_in: int) -> Params:
@@ -187,9 +227,11 @@ def make_block_params(rng, cfg: ModelConfig, i: int, c_in: int) -> Params:
     k = cfg.kernel_sizes[i]
     reps = cfg.repeats[i]
     keys = jax.random.split(rng, reps + 1)
-    p: Params = {f"rep{j}": make_sep_conv_params(
-        keys[j], c_in if j == 0 else c_out, c_out, k) for j in range(reps)}
-    if cfg.use_skips:
+    make = (make_sep_conv_params if cfg.block_separable(i)
+            else make_full_conv_params)
+    p: Params = {f"rep{j}": make(keys[j], c_in if j == 0 else c_out, c_out, k)
+                 for j in range(reps)}
+    if cfg.block_residual(i):
         p["skip_pw"] = truncated_normal_init(keys[-1], (1, c_in, c_out))
         p["skip_bn"] = make_bn_params(c_out)
     return p
@@ -199,7 +241,7 @@ def block_state(cfg: ModelConfig, i: int) -> State:
     c_out = cfg.channels[i]
     s: State = {f"rep{j}": sep_conv_state(c_out)
                 for j in range(cfg.repeats[i])}
-    if cfg.use_skips:
+    if cfg.block_residual(i):
         s["skip_bn"] = make_bn_state(c_out)
     return s
 
@@ -247,28 +289,35 @@ def block_forward(p: Params, s: State, x: jax.Array, cfg: ModelConfig,
     stride = cfg.strides[i]
     k = cfg.kernel_sizes[i]
     tag = f"block{i:02d}"
+    separable = cfg.block_separable(i)
     new_s: State = {}
     h = x
     for j in range(reps):
         last = (j == reps - 1)
         st = stride if j == 0 else 1
         s_rep = s_in if j == 0 else s_in * stride
-        # each grouped (K > 1) conv must see zeros beyond the read edge,
-        # exactly like the whole-read forward's implicit padding; the
-        # pointwise convs / BN / ReLU in between are positionwise and
-        # cannot smear out-of-read values inward, so masking the repeat
-        # inputs is sufficient
+        # each K > 1 conv (grouped or full) must see zeros beyond the read
+        # edge, exactly like the whole-read forward's implicit padding;
+        # the pointwise convs / BN / activation in between are
+        # positionwise and cannot smear out-of-read values inward, so
+        # masking the repeat inputs is sufficient
         if span is None:
             h = _mask_outside(h, bounds, s_rep)
         else:
             at, out = span[j], span[j + 1]
             lo, hi = conv_input_span(out.lo, out.hi, k, st, dilation, causal)
             h = _mask_outside(h[:, lo - at.lo:hi - at.lo], bounds, s_rep, lo)
-        h, ns = sep_conv(p[f"rep{j}"], s[f"rep{j}"], h, cfg, f"{tag}/rep{j}",
-                         stride=st, dilation=dilation, causal=causal,
-                         train=train, relu=not last, same=span is None)
+        kw = dict(stride=st, dilation=dilation, causal=causal, train=train,
+                  act=not last, same=span is None)
+        if separable:
+            h, ns = sep_conv(p[f"rep{j}"], s[f"rep{j}"], h, cfg,
+                             f"{tag}/rep{j}", **kw)
+        else:
+            with jax.named_scope("full"):
+                h, ns = full_conv(p[f"rep{j}"], s[f"rep{j}"], h, cfg,
+                                  f"{tag}/rep{j}", **kw)
         new_s[f"rep{j}"] = ns
-    if cfg.use_skips and "skip_pw" in p:
+    if cfg.block_residual(i) and "skip_pw" in p:
         gate = 1.0 if skip_gate is None else skip_gate
         sk_in = x
         if span is not None:
@@ -276,16 +325,12 @@ def block_forward(p: Params, s: State, x: jax.Array, cfg: ModelConfig,
             at, out = span[0], span[-1]
             sk_in = x[:, out.lo * stride - at.lo:
                       (out.hi - 1) * stride + 1 - at.lo]
-        sk = conv1d(sk_in, conv_kernel_of(p["skip_pw"], x.dtype))
-        if stride > 1:
-            sk = sk[:, ::stride]
-        sk, bn_s = batchnorm(p["skip_bn"], s["skip_bn"], sk, train=train)
+        with jax.named_scope("residual"):
+            sk = conv1d(sk_in, conv_kernel_of(p["skip_pw"], x.dtype))
+            if stride > 1:
+                sk = sk[:, ::stride]
+            sk, bn_s = batchnorm(p["skip_bn"], s["skip_bn"], sk, train=train,
+                                 eps=cfg.norm_eps)
         new_s["skip_bn"] = bn_s
         h = h + gate * sk
-    h = jax.nn.relu(h)
-    if cfg.quant.enabled:
-        from repro.core.quant.fake_quant import fake_quant
-        _, ab = cfg.quant.bits_for(tag + "/act")
-        if ab:
-            h = fake_quant(h, ab)
-    return h, new_s
+    return activate(h, cfg, tag), new_s

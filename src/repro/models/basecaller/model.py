@@ -1,9 +1,12 @@
 """Basecaller model family: RUBICALL (skip-free, mixed-precision), the
-Bonito-style teacher (skips, FP), and the Causalcall-style TCN — one
-parametric implementation driven by :class:`ModelConfig`.
+Bonito-style SkipClip teacher (skips, FP), Bonito's released R9.4.1
+model (full and separable convs, per-block residuals, swish, a head
+with bias) and the Causalcall-style TCN — one parametric implementation
+driven by :class:`ModelConfig` (blocks: :mod:`.blocks`).
 
 Input: normalized squiggle chunks (B, S, 1). Output: CTC log-probs
-(B, S/stem_stride, 5).
+(B, S/stem_stride, 5) from a 1x1 conv head, with a bias where
+``cfg.head_bias``.
 """
 from __future__ import annotations
 
@@ -29,6 +32,8 @@ def init_params(rng, cfg: ModelConfig) -> Params:
         p[f"block{i:02d}"] = bl.make_block_params(keys[i], cfg, i, c_in)
         c_in = cfg.channels[i]
     p["head_pw"] = truncated_normal_init(keys[-1], (1, c_in, cfg.n_bases))
+    if cfg.head_bias:
+        p["head_b"] = jnp.zeros((cfg.n_bases,), jnp.float32)
     return p
 
 
@@ -83,8 +88,11 @@ def forward(params: Params, state: State, signal: jax.Array,
         if spans is not None:
             at = spans[i][-1]
     with jax.named_scope("head"):
-        logits = bl.conv1d(x, bl.conv_kernel_of(params["head_pw"], x.dtype))
-        log_probs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        logits = bl.conv1d(x, bl.conv_kernel_of(params["head_pw"], x.dtype)
+                           ).astype(jnp.float32)
+        if cfg.head_bias:
+            logits = logits + params["head_b"].astype(jnp.float32)
+        log_probs = jax.nn.log_softmax(logits, axis=-1)
     return log_probs, new_state
 
 

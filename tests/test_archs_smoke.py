@@ -48,6 +48,7 @@ def test_param_count_scale(arch):
         "granite-moe-1b-a400m": 1.3e9, "deepseek-v3-671b": 671e9,
         "whisper-tiny": 37e6,
         "rubicall": 3.3e6, "bonito": 10e6, "causalcall": 3.5e6,
+        "bonito-r9.4.1": 6.6e6,
     }[arch]
     cfg = get_config(arch)
     n = api.count_params_analytic(cfg)

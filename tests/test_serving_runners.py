@@ -7,6 +7,8 @@ output == the offline whole-read forward + greedy/beam CTC decode
 (bit-exact for non-act-quantized configs — the chunked forward with
 read-edge masking reproduces the whole-read forward exactly).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -154,14 +156,22 @@ def _offline_logp(params, cfg, sig):
     return np.asarray(lp)[0]
 
 
-def test_basecaller_serves_with_whole_read_parity():
+@pytest.mark.parametrize("arch,repeats", [
+    ("bonito-smoke", None),
+    # full stem, per-block residuals, swish, head bias; 1 and 2-3 repeats
+    ("bonito-r9.4.1-smoke", None), ("bonito-r9.4.1-smoke", (1, 2, 3, 1))])
+def test_basecaller_serves_with_whole_read_parity(arch, repeats):
     """bonito reads through the engine: mixed lengths (including reads
     shorter than one chunk and lengths not divisible by the stride or
     chunk), 2 slots for 4 reads (slot recycling), greedy CTC merge ==
     offline whole-read greedy basecall EXACTLY."""
-    cfg = get_config("bonito-smoke")
+    cfg = get_config(arch)
+    if repeats:
+        cfg = dataclasses.replace(cfg, repeats=repeats)
     params = api.init_params(jax.random.key(0), cfg)
-    eng = ServingEngine(params, cfg, n_slots=2, chunk_samples=300)
+    if cfg.head_bias:
+        params["head_b"] = jax.random.normal(jax.random.key(2), (cfg.n_bases,))
+    eng = api.make_serving_engine(params, cfg, n_slots=2, chunk_samples=300)
     assert runner_name_for(cfg) == "basecaller"
     sigs = _reads(2, (700, 901, 250, 505))
     for i, s in enumerate(sigs):

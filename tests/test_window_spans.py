@@ -37,17 +37,25 @@ def _case(name):
         params = bc.init_params(jax.random.key(1), cfg)
         return cfg, quantize_tree(params, QuantPolicy(weight_bits=8,
                                                       act_bits=0), min_size=1)
-    if name == "bonito-smoke-repeats":
+    if name.endswith("-repeats"):
         # several repeats a block: one span per repeat, skip over them
-        cfg = replace(get_config("bonito-smoke"), repeats=(1, 2, 3, 1))
+        cfg = replace(get_config(name[:-len("-repeats")]),
+                      repeats=(1, 2, 3, 1))
     else:
         cfg = _no_act_quant(get_config(name))
-    return cfg, bc.init_params(jax.random.key(0), cfg)
+    params = bc.init_params(jax.random.key(0), cfg)
+    if cfg.head_bias:
+        # a bias that moves the log-probs (init_params draws zeros)
+        params["head_b"] = jax.random.normal(jax.random.key(2), (cfg.n_bases,))
+    return cfg, params
 
 
 @pytest.mark.parametrize("name", ["rubicall-smoke", "bonito-smoke",
                                   "causalcall-smoke", "bonito-smoke-repeats",
-                                  "rubicall-smoke-int8"])
+                                  "rubicall-smoke-int8",
+                                  # full stem, residuals, swish, head bias
+                                  "bonito-r9.4.1-smoke",
+                                  "bonito-r9.4.1-smoke-repeats"])
 def test_window_forward_equals_full_window_forward_on_the_core(name):
     cfg, params = _case(name)
     state = bc.init_state(cfg)
